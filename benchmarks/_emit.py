@@ -12,7 +12,10 @@ import json
 import os
 import platform
 import subprocess
+import sys
 import time
+
+SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
 
 
 def peak_rss_mb() -> float:
@@ -31,6 +34,27 @@ def peak_rss_mb() -> float:
         return round(rss * scale / 1024.0, 2)
     except Exception:
         return 0.0
+
+
+def run_cpu_child(script: str, args, devices: int, timeout: float) -> dict:
+    """Run a bench worker on ``devices`` forced host CPU devices and
+    return the JSON record it prints last, stamped ``platform: cpu``.
+
+    The child emulates a ``devices``-worker mesh on virtual CPU devices.
+    ``JAX_PLATFORMS=cpu`` is explicit: a parent on a chip host may hold
+    the chip, and a child that reached for it would fail or hang."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join([SRC, env.get("PYTHONPATH", "")])
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, script, *map(str, args)],
+                         env=env, capture_output=True, text=True,
+                         timeout=timeout)
+    if out.returncode != 0:
+        raise RuntimeError(out.stderr[-2000:])
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    rec["platform"] = "cpu"
+    return rec
 
 
 def _git_sha() -> str:
@@ -56,18 +80,22 @@ def emit(name: str, rows: list, meta: dict | None = None,
     Every record is stamped with the git SHA and jax version so the
     nightly bench trajectory is attributable to a commit + toolchain,
     and with the process tree's peak RSS so memory-wall claims are
-    measured, not inferred.
+    measured, not inferred. A record taken off the CPU must name its
+    device: an accelerator row without a ``device_kind`` cannot be
+    compared with anything, so it raises instead of being written.
     """
-    try:
-        import jax
-        backend = jax.default_backend()
-        devices = jax.devices()
-        n_devices = len(devices)
-        device_kind = devices[0].device_kind if devices else "unknown"
-        jax_version = jax.__version__
-    except Exception:  # bench records must never die on metadata
-        backend, n_devices, jax_version = "unknown", 0, "unknown"
-        device_kind = "unknown"
+    import jax
+    devices = jax.devices()
+    backend = devices[0].platform
+    n_devices = len(devices)
+    device_kind = getattr(devices[0], "device_kind", "") or ""
+    if not device_kind:
+        if backend != "cpu":
+            raise RuntimeError(
+                f"bench record on platform {backend!r} cannot name its "
+                "device kind; refusing to write it")
+        device_kind = "cpu"
+    jax_version = jax.__version__
     rec = {
         "bench": name,
         "rows": rows,

@@ -27,6 +27,8 @@ def synth_graph(n: int, deg: int, seed: int = 0):
 
 
 def main(n: int, deg: int, sweep: str) -> None:
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     from repro.graph.affinity import run_graph_affinity
 
     el = synth_graph(n, deg)

@@ -22,6 +22,8 @@ from repro.solver import solve
 
 
 def main(n: int, levels: int, iterations: int, mode: str) -> None:
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     x, _ = gaussian_blobs(n=n, k=7, seed=0)
     workers = len(jax.devices())
     backend = f"mr1d_{mode}"

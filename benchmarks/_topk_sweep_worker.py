@@ -33,6 +33,8 @@ def synth_topk(n: int, k: int, levels: int, seed: int = 0):
 
 def main(n: int, k: int, levels: int, iterations: int, sweep: str,
          exchange: str) -> None:
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     from repro.solver.topk import run_topk
     from repro.solver.topk_sharded import (
         comm_bytes_per_sweep, resolve_exchange, run_topk_sharded)

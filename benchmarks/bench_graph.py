@@ -7,9 +7,9 @@ Two suites, both emitted to ``BENCH_graph.json``:
   regime the backend targets), single device; records rounds to
   convergence (the ~log2 N claim on record), wall, and us/round;
 * ``workers`` — one size run at 1 and 8 forced host devices
-  (subprocesses, same pattern as bench_scaling) so the shard_map
-  exchange overhead vs the row-block win is on record. On this CPU
-  container 8 "workers" share the host — the row gates dispatch and
+  (subprocesses on virtual CPU devices, same pattern as bench_scaling)
+  so the shard_map exchange overhead vs the row-block win is on record.
+  The 8 "workers" share one host's CPU — the row gauges dispatch and
   collective overhead, not real scaling.
 
     PYTHONPATH=src python benchmarks/bench_graph.py [--smoke]
@@ -19,31 +19,18 @@ Two suites, both emitted to ``BENCH_graph.json``:
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import subprocess
-import sys
 
 try:
-    from benchmarks._emit import emit
+    from benchmarks._emit import emit, run_cpu_child
 except ImportError:  # executed as a script: benchmarks/ is sys.path[0]
-    from _emit import emit
+    from _emit import emit, run_cpu_child
 
 WORKER = os.path.join(os.path.dirname(__file__), "_graph_worker.py")
 
 
 def _run_worker(n: int, deg: int, sweep: str, workers: int) -> dict:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env.get("PYTHONPATH", "")])
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={workers}"
-    out = subprocess.run(
-        [sys.executable, WORKER, str(n), str(deg), sweep], env=env,
-        capture_output=True, text=True, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    return run_cpu_child(WORKER, (n, deg, sweep), workers, timeout=900)
 
 
 def run(sizes, deg: int, worker_n: int) -> list:

@@ -57,6 +57,8 @@ def run(subsample: int = 8, levels: int = 3, iterations: int = 30,
 
 
 def main():
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     rows = run()
     for r in rows:
         print(f"image_{r['image']},{r['wall_s'] * 1e6:.0f},"

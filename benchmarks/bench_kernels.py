@@ -293,6 +293,8 @@ def run_topk_build(tier: str) -> list:
 
 
 def main(argv=None):
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes / 1 rep: CI compile-regression check")

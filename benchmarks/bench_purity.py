@@ -57,6 +57,8 @@ def run(levels: int = 3, iterations: int = 40, topk_k: int = 32) -> list:
 
 
 def main():
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     rows = run()
     for r in rows:
         print(f"purity_{r['dataset']}_L{r['level']},"

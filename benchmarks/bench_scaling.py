@@ -14,7 +14,9 @@ scaling columns the paper's figure is about:
                     transpose mode vs the beyond-paper stats mode
 
 Workers run in subprocesses (benchmarks/_scaling_worker.py) so each sees
-its own forced device count.
+its own forced device count; they run on virtual CPU devices
+(``JAX_PLATFORMS=cpu``, rows stamped ``platform: cpu``), never on a chip
+the parent may hold.
 
 ``topk`` — dense vs sparse single-device scaling out to N = 2*10^5: the
 dense backends stop at the quadratic-state budget (rows past the cap are
@@ -48,18 +50,15 @@ paper-scale N = 1e6 / 1e7 run).
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 import time
 
 from repro.core.mrhap import comm_bytes_per_iteration
 
 try:
-    from benchmarks._emit import emit, peak_rss_mb
+    from benchmarks._emit import emit, peak_rss_mb, run_cpu_child
 except ImportError:  # executed as a script: benchmarks/ is sys.path[0]
-    from _emit import emit, peak_rss_mb
+    from _emit import emit, peak_rss_mb, run_cpu_child
 
 WORKER = os.path.join(os.path.dirname(__file__), "_scaling_worker.py")
 SWEEP_WORKER = os.path.join(os.path.dirname(__file__),
@@ -74,21 +73,10 @@ DENSE_STATE_CAP = 4096
 def run(n: int = 512, levels: int = 3, iterations: int = 20,
         worker_counts=(1, 2, 4, 8), modes=("stats", "transpose")) -> list:
     rows = []
-    env_base = dict(os.environ)
-    env_base["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env_base.get("PYTHONPATH", "")])
     for mode in modes:
         for w in worker_counts:
-            env = dict(env_base)
-            env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={w}"
-            out = subprocess.run(
-                [sys.executable, WORKER, str(n), str(levels),
-                 str(iterations), mode], env=env, capture_output=True,
-                text=True, timeout=900)
-            if out.returncode != 0:
-                raise RuntimeError(out.stderr[-2000:])
-            rec = json.loads(out.stdout.strip().splitlines()[-1])
+            rec = run_cpu_child(WORKER, (n, levels, iterations, mode), w,
+                                timeout=900)
             rec["work_per_worker"] = iterations * levels * n * n // w
             rec["comm_bytes_iter"] = comm_bytes_per_iteration(
                 n, levels, w, mode)
@@ -142,22 +130,12 @@ def run_sweep_scaling(sizes=(65536, 262144, 1_000_000), k: int = 16,
     state / per-sweep communication columns next to the measured wall
     time.
     """
-    env_base = dict(os.environ)
-    env_base["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env_base.get("PYTHONPATH", "")])
     rows = []
     for n in sizes:
         for sweep, w in (("single", 1), ("sharded", sharded_workers)):
-            env = dict(env_base)
-            env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={w}"
-            out = subprocess.run(
-                [sys.executable, SWEEP_WORKER, str(n), str(k), str(levels),
-                 str(iterations), sweep, exchange], env=env,
-                capture_output=True, text=True, timeout=3000)
-            if out.returncode != 0:
-                raise RuntimeError(out.stderr[-2000:])
-            rec = json.loads(out.stdout.strip().splitlines()[-1])
+            rec = run_cpu_child(SWEEP_WORKER,
+                                (n, k, levels, iterations, sweep, exchange),
+                                w, timeout=3000)
             rec["suite"] = "topk_sweep"
             rows.append(rec)
     return rows
@@ -218,6 +196,8 @@ def run_coarsen_scaling(sizes=(200_000, 1_000_000), topk_cap=1_000_000,
 
 
 def main(argv=None):
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--tier", choices=("smoke", "full"), default="full",

@@ -346,6 +346,8 @@ def run_chaos(args) -> int:
 
 
 def main(argv=None) -> int:
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes/loads for CI")

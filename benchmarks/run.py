@@ -14,6 +14,8 @@ import sys
 
 
 def main(argv=None) -> int:
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: images,scaling,purity,kernels,roofline")

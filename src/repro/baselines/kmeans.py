@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.compat import shard_map
-
 
 class KMeansResult(NamedTuple):
     centers: jnp.ndarray   # (k, d)
@@ -91,7 +89,7 @@ def kmeans_distributed(
         return centers, labels.astype(jnp.int32), jax.lax.psum(
             jnp.sum(d2), axis_name)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name, None), P(None, None)),
         out_specs=(P(None, None), P(axis_name), P()),

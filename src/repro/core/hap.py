@@ -50,6 +50,27 @@ class HAPResult(NamedTuple):
 
 
 # ---------------------------------------------------------------- per-level
+def column_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over rows (axis 0) in one fixed pairwise order: the rows are
+    zero-padded to a power of two and the lower half folded onto the upper
+    half until one row is left.
+
+    Only elementwise adds, so the rounding is the same in every program
+    that computes it — batched under ``vmap`` or not, whatever XLA fuses —
+    and trailing zero rows change nothing: a request padded with inert rows
+    sums exactly as it does unpadded. (A ``jnp.sum`` leaves the order to
+    the compiler, which picks it per program and shape.)"""
+    rows = x.shape[0]
+    half = (1 << (rows - 1).bit_length()) // 2     # pow2 >= rows, halved
+    while half:
+        lo, hi = x[:half], x[half:]
+        if hi.shape[0] < half:
+            hi = jnp.pad(hi, [(0, half - hi.shape[0])]
+                         + [(0, 0)] * (x.ndim - 1))
+        x, half = lo + hi, half // 2
+    return x[0]
+
+
 def rho_update(s: jnp.ndarray, a: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
     """Eq 2.1: rho_ij = s_ij + min(tau_i, -max_{k!=j}(a_ik + s_ik))."""
     v = a + s
@@ -66,7 +87,7 @@ def alpha_update(
     n = r.shape[-1]
     eye = jnp.eye(n, dtype=bool)
     rp = jnp.where(eye, 0.0, jnp.maximum(r, 0.0))  # max(0, rho_kj), k != j
-    col = jnp.sum(rp, axis=0)                      # (N,) sum_{k != j}
+    col = column_sum(rp)                           # (N,) sum_{k != j}
     rdiag = jnp.diagonal(r)
     base = c[None, :] + phi[None, :]
     a_off = jnp.minimum(0.0, base + rdiag[None, :] + col[None, :] - rp)
@@ -78,7 +99,7 @@ def tau_from_level(r: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     """Eq 2.4: tau_j^{l+1} = c_j^l + rho_jj^l + sum_{k!=j} max(0, rho_kj^l)."""
     n = r.shape[-1]
     eye = jnp.eye(n, dtype=bool)
-    col = jnp.sum(jnp.where(eye, 0.0, jnp.maximum(r, 0.0)), axis=0)
+    col = column_sum(jnp.where(eye, 0.0, jnp.maximum(r, 0.0)))
     return c + jnp.diagonal(r) + col
 
 

@@ -36,7 +36,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import hap
 from repro.core.affinity import masked_top2
-from repro.sharding.compat import pvary, shard_map
 
 CommMode = Literal["stats", "transpose"]
 AXIS = "workers"
@@ -80,12 +79,18 @@ def _alpha_rows(r, c_g, phi_g, col_g, diag_g, rows):
     return jnp.where(eye[None], a_diag, a_off)
 
 
+#: per-level column sums in the dense core's fixed order
+#: (``hap.column_sum``): on one worker the shard's partial sums are then
+#: the dense sweep's own
+_col_sums = jax.vmap(hap.column_sum)
+
+
 def _col_stats_rows(r, rows):
     """Partial column sums of max(0, rho) excluding the diagonal, plus the
     locally-owned diagonal slice. Shapes: (L, N) partial, (L, Nl) diag."""
     n = r.shape[-1]
     eye = rows[:, None] == jnp.arange(n)[None, :]
-    col_part = jnp.sum(jnp.where(eye[None], 0.0, jnp.maximum(r, 0.0)), axis=1)
+    col_part = _col_sums(jnp.where(eye[None], 0.0, jnp.maximum(r, 0.0)))
     nl = rows.shape[0]
     diag_loc = r[:, jnp.arange(nl), rows]                  # (L, Nl)
     return col_part, diag_loc
@@ -155,7 +160,7 @@ def _sweep_transpose(carry, it, *, s_row, s_col, lam, n_local):
     # --- Job 1 mapper side: column statistics from exemplar-based rho ---
     eye_col = jnp.arange(n)[:, None] == rows[None, :]      # (N, Nl)
     rp = jnp.where(eye_col[None], 0.0, jnp.maximum(r_col, 0.0))
-    col_loc = jnp.sum(rp, axis=1)                          # (L, Nl)
+    col_loc = _col_sums(rp)                                # (L, Nl)
     diag_loc = r_col[:, rows, jnp.arange(n_local)]         # (L, Nl)
     col_g = jax.lax.all_gather(col_loc, AXIS, axis=1, tiled=True)
     diag_g = jax.lax.all_gather(diag_loc, AXIS, axis=1, tiled=True)
@@ -186,7 +191,7 @@ def _sweep_transpose(carry, it, *, s_row, s_col, lam, n_local):
     phi_g = jax.lax.all_gather(phi_loc, AXIS, axis=1, tiled=True)
 
     rp_new = jnp.where(eye_col[None], 0.0, jnp.maximum(r_col, 0.0))
-    col_new = jnp.sum(rp_new, axis=1)                      # (L, Nl) local cols
+    col_new = _col_sums(rp_new)                            # (L, Nl) local cols
     rdiag_new = r_col[:, rows, jnp.arange(n_local)]        # (L, Nl)
     c_cols = _slice_rows(c_g, w, n_local)
     phi_cols = _slice_rows(phi_g, w, n_local)
@@ -205,7 +210,7 @@ def _run_body_stats(s3, *, iterations, lam, n_local):
     levels, _, n = s3.shape
     zero_g = jnp.zeros((levels, n), s3.dtype)
     # all_gather outputs are vma-varying over AXIS; match the carry types.
-    vary = lambda x: pvary(x, (AXIS,))
+    vary = lambda x: jax.lax.pcast(x, (AXIS,), to="varying")
     carry = (z, z, vary(zero_g), zero_g, vary(zero_g))
     sweep = functools.partial(_sweep_stats, s_loc=s3, lam=lam, n_local=n_local)
     carry, _ = jax.lax.scan(sweep, carry, jnp.arange(iterations))
@@ -218,7 +223,8 @@ def _run_body_transpose(s_row, s_col, *, iterations, lam, n_local):
     levels, _, n = s_row.shape
     z_row = jnp.zeros_like(s_row)
     z_col = jnp.zeros_like(s_col)
-    zero_g = pvary(jnp.zeros((levels, n), s_row.dtype), (AXIS,))
+    zero_g = jax.lax.pcast(jnp.zeros((levels, n), s_row.dtype), (AXIS,),
+                           to="varying")
     carry = (z_row, z_col, z_col, zero_g)
     sweep = functools.partial(
         _sweep_transpose, s_row=s_row, s_col=s_col, lam=lam, n_local=n_local)
@@ -276,13 +282,13 @@ def _mrhap_program(mesh: Mesh, axis_name: str, comm_mode: CommMode,
         body = functools.partial(
             _run_body_stats, iterations=iterations, lam=damping,
             n_local=n_local)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(row_spec,),
             out_specs=(vec_spec, row_spec, row_spec)))
     body = functools.partial(
         _run_body_transpose, iterations=iterations, lam=damping,
         n_local=n_local)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(row_spec, col_spec),
         out_specs=(vec_spec, row_spec, row_spec)))
 
@@ -399,7 +405,7 @@ def _sweep_stats_2d(carry, it, *, s_loc, lam, nr_loc, nc_loc):
 
     eye = rows[:, None] == cols[None, :]
     rp = jnp.where(eye[None], 0.0, jnp.maximum(r, 0.0))
-    col_c = jax.lax.psum(jnp.sum(rp, axis=1), AXIS_R)     # (L, nc_loc)
+    col_c = jax.lax.psum(_col_sums(rp), AXIS_R)           # (L, nc_loc)
     diag_c = jax.lax.psum(
         jnp.sum(jnp.where(eye[None], r, 0.0), axis=1), AXIS_R)
     base = (jax.lax.dynamic_slice_in_dim(c_g, ci * nc_loc, nc_loc, 1)
@@ -412,7 +418,7 @@ def _sweep_stats_2d(carry, it, *, s_loc, lam, nr_loc, nc_loc):
 
 def _run_body_2d(s_loc, *, iterations, lam, nr_loc, nc_loc, n, levels):
     z = jnp.zeros_like(s_loc)
-    vary = lambda x, ax: pvary(x, ax)
+    vary = lambda x, ax: jax.lax.pcast(x, ax, to="varying")
     # vma bookkeeping: all_gather over R -> varying {R}; psum over R of a
     # tile-varying value -> varying {C}.
     c_g = vary(jnp.zeros((levels, n), s_loc.dtype), (AXIS_R,))
@@ -460,6 +466,6 @@ def _mrhap_2d_program(mesh: Mesh, row_axis: str, col_axis: str,
         _run_body_2d, iterations=iterations, lam=damping,
         nr_loc=nr_loc, nc_loc=nc_loc, n=n, levels=levels)
     tile = P(None, row_axis, col_axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(tile,),
         out_specs=(P(None, row_axis), tile, tile)))

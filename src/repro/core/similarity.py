@@ -23,12 +23,33 @@ Metric = Literal["neg_sqeuclidean", "neg_euclidean", "cosine"]
 # NaN-free under +/- and damping.
 NEG_LARGE = -1.0e9
 
+#: f32 matmul precision of every similarity build: the builders here, the
+#: Pallas similarity and fused top-k kernels, and the two-stage top-k
+#: build. ||x||^2 + ||y||^2 - 2 x.y cancels, so the cross term needs full
+#: f32 products; a TPU's default single bf16 pass would move near
+#: neighbours by far more than the gaps between them. CPU f32 dots are
+#: full precision either way.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _sq_norms(x: jnp.ndarray) -> jnp.ndarray:
+    """Row norms ||x||^2 summed left to right from separately rounded
+    squares. The barrier keeps XLA from fusing a square into the next
+    add as one fused multiply-add, which it does in some programs and
+    not others: the same points then get the same norms in a batched
+    serving program and in an unbatched solve."""
+    sq = jax.lax.optimization_barrier(x * x)
+    out = sq[..., 0]
+    for k in range(1, x.shape[-1]):
+        out = out + sq[..., k]
+    return out
+
 
 def _neg_sqeuclidean(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     # ||x-y||^2 = ||x||^2 + ||y||^2 - 2 x.y  (MXU-friendly: one matmul)
-    xx = jnp.sum(x * x, axis=-1)[:, None]
-    yy = jnp.sum(y * y, axis=-1)[None, :]
-    xy = x @ y.T
+    xx = _sq_norms(x)[:, None]
+    yy = _sq_norms(y)[None, :]
+    xy = jnp.matmul(x, y.T, precision=MATMUL_PRECISION)
     d2 = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     return -d2
 
@@ -41,7 +62,7 @@ def _cosine(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     xn = x / (jnp.linalg.norm(x, axis=-1, keepdims=True) + 1e-12)
     yn = y / (jnp.linalg.norm(y, axis=-1, keepdims=True) + 1e-12)
     # cosine similarity in [-1, 1]; shift to <= 0 per the paper's convention.
-    return xn @ yn.T - 1.0
+    return jnp.matmul(xn, yn.T, precision=MATMUL_PRECISION) - 1.0
 
 _METRICS: dict[str, Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]] = {
     "neg_sqeuclidean": _neg_sqeuclidean,

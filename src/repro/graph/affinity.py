@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import pvary, shard_map
 from repro.sharding.partitioning import device_put_row_sharded
 
 AXIS = "workers"
@@ -185,14 +184,14 @@ def _graph_program(mesh, levels: int, n_local: int, n_total: int,
 
         hist, r, conv, trace = _loop(
             select, levels, n_total, n_real, max_rounds, target, jump_iters)
-        vary = lambda x: pvary(x, (AXIS,))
+        vary = lambda x: jax.lax.pcast(x, (AXIS,), to="varying")
         scal = lambda v: vary(jnp.reshape(v, (1,)))
         # every worker holds identical (collective-derived) full-length
         # labels; emit each worker's own row slice so outputs reassemble
         # under sharded specs (no replicated-output spec needed)
         return (vary(hist)[:, rows], scal(r), scal(conv), vary(trace)[None])
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None)),
         out_specs=(P(None, AXIS), P(AXIS), P(AXIS), P(AXIS, None))))

@@ -4,9 +4,18 @@
 import jax
 
 
-def default_interpret() -> bool:
-    """Pallas ``interpret`` default: compile natively on TPU, emulate
-    elsewhere (this CPU container). Kernel entry points take
-    ``interpret=None`` and resolve it here at call time, so the same call
-    site is the correctness harness on CPU and the hot path on TPU."""
-    return jax.default_backend() != "tpu"
+def default_interpret(interpret: bool | None = None) -> bool:
+    """Resolve a kernel entry point's ``interpret`` argument.
+
+    ``None`` compiles natively on TPU and emulates elsewhere (the CPU
+    test harness), so the same call site is the correctness harness on
+    CPU and the hot path on TPU. An explicit ``interpret=True`` on a TPU
+    backend is refused: an emulated kernel there would report a result
+    the chip never computed."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU backend: Pallas kernels "
+                         "run natively on the chip")
+    return bool(interpret)

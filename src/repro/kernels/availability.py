@@ -76,8 +76,7 @@ def availability_pallas(
     Padding neutral: r padded with -1 (clamped to 0 in the column sums and
     never on the diagonal of a real column).
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     n, m = r.shape
     bi, bj = min(block_i, n), min(block_j, m)
     pn, pm = (-n) % bi, (-m) % bj

@@ -85,8 +85,7 @@ def flash_attention_pallas(
     KV heads. Sq/Sk padded to block multiples with masked tail (pad keys
     get -inf scores via the causal/row guard: pad rows emit zeros).
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq, bk = min(block_q, sq), min(block_k, sk)

@@ -13,15 +13,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret, ref
+from repro.kernels import ref
 from repro.kernels.availability import availability_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.responsibility import responsibility_pallas
 from repro.kernels.similarity import similarity_pallas
-
-
-def _interpret() -> bool:
-    return default_interpret()
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "block", "use_ref"))
@@ -30,8 +26,7 @@ def responsibility(s, a, tau, r_old, *, lam: float = 0.5, block: int = 256,
     if use_ref:
         return ref.responsibility(s, a, tau, r_old, lam)
     return responsibility_pallas(
-        s, a, tau, r_old, lam, block_i=block, block_j=block,
-        interpret=_interpret())
+        s, a, tau, r_old, lam, block_i=block, block_j=block)
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "block", "use_ref"))
@@ -40,16 +35,14 @@ def availability(r, c, phi, a_old, *, lam: float = 0.5, block: int = 256,
     if use_ref:
         return ref.availability(r, c, phi, a_old, lam)
     return availability_pallas(
-        r, c, phi, a_old, lam, block_i=block, block_j=block,
-        interpret=_interpret())
+        r, c, phi, a_old, lam, block_i=block, block_j=block)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "use_ref"))
 def neg_sqeuclidean(x, y=None, *, block: int = 256, use_ref: bool = False):
     if use_ref:
         return ref.neg_sqeuclidean(x, x if y is None else y)
-    return similarity_pallas(x, y, block_i=block, block_j=block,
-                             interpret=_interpret())
+    return similarity_pallas(x, y, block_i=block, block_j=block)
 
 
 def hap_iteration_kernels(s, r, a, tau, c, phi, *, lam: float = 0.5,
@@ -73,7 +66,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block: int = 256,
     if use_ref:
         return ref.flash_attention(q, k, v, causal)
     return flash_attention_pallas(q, k, v, causal=causal, block_q=block,
-                                  block_k=block, interpret=_interpret())
+                                  block_k=block)
 
 
 @functools.partial(jax.jit,

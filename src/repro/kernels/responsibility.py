@@ -79,8 +79,7 @@ def responsibility_pallas(
     N, M need not be tile-aligned — inputs are padded with neutral values
     (-inf similarities never win the max; padded rows get tau = 0).
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     n, m = s.shape
     bi, bj = min(block_i, n), min(block_j, m)
     pn, pm = (-n) % bi, (-m) % bj

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.similarity import MATMUL_PRECISION
 from repro.kernels import default_interpret
 
 
@@ -27,6 +28,7 @@ def _sim_kernel(x_ref, y_ref, out_ref):
     yy = jnp.sum(y * y, axis=1, keepdims=True).T        # (1, bj)
     xy = jax.lax.dot_general(
         x, y, (((1,), (1,)), ((), ())),
+        precision=MATMUL_PRECISION,
         preferred_element_type=jnp.float32)             # (bi, bj) on the MXU
     d2 = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     out_ref[...] = (-d2).astype(out_ref.dtype)
@@ -42,8 +44,7 @@ def similarity_pallas(
     ``interpret=None`` derives the mode from the backend (native on
     TPU, emulated elsewhere) — see ``repro.kernels.default_interpret``.
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     if y is None:
         y = x
     n, d = x.shape
@@ -65,7 +66,11 @@ def similarity_pallas(
             pl.BlockSpec((bj, dpad), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((npad, mpad), x.dtype),
+        # inside shard_map (the sharded build's per-worker tiles) the
+        # output varies over the manual axes its operands vary over
+        out_shape=jax.ShapeDtypeStruct(
+            (npad, mpad), x.dtype,
+            vma=jax.typeof(x).vma | jax.typeof(y).vma),
         interpret=interpret,
     )(x, y)
     return out[:n, :m]

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.similarity import MATMUL_PRECISION
 from repro.kernels import default_interpret
 
 NEG_INF = float("-inf")
@@ -44,6 +45,7 @@ def _build_kernel(xr_ref, xc_ref, vals_ref, idx_ref, *, k, n, br, bc,
     yy = jnp.sum(y * y, axis=1, keepdims=True).T         # (1, bc)
     xy = jax.lax.dot_general(
         x, y, (((1,), (1,)), ((), ())),
+        precision=MATMUL_PRECISION,
         preferred_element_type=jnp.float32)              # (br, bc) MXU
     s = -jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     if interpret:
@@ -103,11 +105,10 @@ def topk_similarity_fused(
 
     Same output contract as ``repro.kernels.topk_similarity`` (ascending
     column layout, (value desc, col asc) tie-break) — the parity suite
-    holds them bit-equal. Block sizes default small enough that the
+    holds them to the same edges, values within the numeric contract. Block sizes default small enough that the
     (br, k + bc) candidate buffers sit comfortably in VMEM.
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     n, d = x.shape
     if not 1 <= k <= n - 1:
         raise ValueError(f"k must be in [1, N-1] = [1, {n - 1}]; got {k}")

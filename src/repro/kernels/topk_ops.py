@@ -50,7 +50,7 @@ def col_partial_topk(r: jnp.ndarray, idx: jnp.ndarray,
     excluded. On one device (``n_total == N``, all rows) this IS the full
     column statistic; a row-sharded sweep psums the per-shard partials
     (or all-gathers rho and scatters the full edge set at once — the
-    bit-exact exchange, same accumulation order as this single scatter).
+    allgather exchange, same accumulation order as this single scatter).
     """
     rp = jnp.maximum(r, 0.0).at[:, 0].set(0.0)      # self slot excluded
     return jnp.zeros((n_total,), r.dtype).at[idx.ravel()].add(rp.ravel())
@@ -105,9 +105,14 @@ def tau_from_stats(c: jnp.ndarray, rdiag: jnp.ndarray,
 
 
 def tau_topk(r: jnp.ndarray, c: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """Eq 2.4: tau_j^{l+1} = c_j + rho_jj + sum_{k!=j} max(0, rho_kj)."""
+    """Eq 2.4: tau_j^{l+1} = c_j + rho_jj + sum_{k!=j} max(0, rho_kj).
+
+    The column sum is read back through the self slot's row ids, as a
+    row-sharded caller reads its block: added straight to the scatter's
+    result, ``c + rho_jj`` would be folded by XLA into the scatter's
+    initial value, which sums the same terms in another order."""
     col, rdiag = col_stats_topk(r, idx)
-    return tau_from_stats(c, rdiag, col)
+    return tau_from_stats(c, rdiag, col[idx[:, 0]])
 
 
 def phi_topk(a: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:

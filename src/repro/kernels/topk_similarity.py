@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.similarity import _METRICS
+from repro.core.similarity import _METRICS, MATMUL_PRECISION, _sq_norms
 
 NEG_INF = float("-inf")
 
@@ -179,6 +179,13 @@ def topk_similarity(
 
         init = (jnp.full((br, k), NEG_INF, jnp.float32),
                 jnp.zeros((br, k), jnp.int32))
+        # under shard_map the folded tiles vary over the manual axes
+        # their inputs vary over; the scan carry must start that way too
+        vma = tuple(sorted(jax.typeof(tile).vma | jax.typeof(rows).vma
+                           | jax.typeof(col_pad).vma))
+        if vma:
+            init = jax.tree.map(
+                lambda v: jax.lax.pcast(v, vma, to="varying"), init)
         (vals, idx), _ = jax.lax.scan(
             fold, init, jnp.arange(n_ct, dtype=jnp.int32) * bc)
         # deterministic layout: neighbors in ascending column order
@@ -255,7 +262,7 @@ def topk_similarity_twostage(
     row_offset=0,
     perm: np.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Threshold-gated two-stage top-k build; bit-identical edge set to
+    """Threshold-gated two-stage top-k build; the same edge set as
     ``topk_similarity`` (enforced in tests), typically an order of
     magnitude less work on clusterable data.
 
@@ -302,7 +309,7 @@ def _twostage_core(x, y, perm, row_offset, *, k, metric, block_rows,
     yp = jnp.pad(jnp.take(gy, perm, axis=0), ((0, pad), (0, 0)))
     gcol = jnp.pad(perm, (0, pad), constant_values=n)   # n = phantom
     valid = gcol < n
-    yy = jnp.where(valid, jnp.sum(yp * yp, axis=1), jnp.inf)
+    yy = jnp.where(valid, _sq_norms(yp), jnp.inf)
     ych = yp.reshape(nch, cw, d)
     wch = valid.reshape(nch, cw).astype(jnp.float32)
     cnt = jnp.maximum(jnp.sum(wch, axis=1), 1.0)
@@ -323,10 +330,12 @@ def _twostage_core(x, y, perm, row_offset, *, k, metric, block_rows,
     def row_tile(args):
         tile, r0 = args                                # (br, d) geometry
         rows = row_offset + r0 + jnp.arange(br)
-        txx = jnp.sum(tile * tile, axis=1)
+        txx = _sq_norms(tile)
         d2c = jnp.maximum(txx[:, None]
                           + jnp.sum(cen * cen, axis=1)[None, :]
-                          - 2.0 * (tile @ cen.T), 0.0)  # (br, nch)
+                          - 2.0 * jnp.matmul(tile, cen.T,
+                                             precision=MATMUL_PRECISION),
+                          0.0)                          # (br, nch)
         # squared lower bound on the distance to anything in the chunk
         lbd = jnp.maximum(jnp.sqrt(d2c) * (1.0 - _GATE_REL) - rad, 0.0)
         lbd2 = lbd * lbd
@@ -341,7 +350,8 @@ def _twostage_core(x, y, perm, row_offset, *, k, metric, block_rows,
             exact similarities into the carry."""
             sw = cid.shape[1] * cw
             pts = jnp.take(ych, cid, axis=0)            # (br, S', cw, d)
-            dot = jnp.einsum("rd,rscd->rsc", tile, pts).reshape(br, sw)
+            dot = jnp.einsum("rd,rscd->rsc", tile, pts,
+                             precision=MATMUL_PRECISION).reshape(br, sw)
             yyg = jnp.take(yych, cid, axis=0).reshape(br, sw)
             cols_ = jnp.take(ccol, cid, axis=0).reshape(br, sw)
             d2 = jnp.maximum(txx[:, None] + yyg - 2.0 * dot, 0.0)
@@ -409,7 +419,8 @@ def _twostage_core(x, y, perm, row_offset, *, k, metric, block_rows,
 
             def run(_):
                 ypg = jax.lax.dynamic_slice_in_dim(ypr, c0, G * cw)
-                dot = tile @ ypg.T                       # (br, G*cw)
+                dot = jnp.matmul(tile, ypg.T,
+                                 precision=MATMUL_PRECISION)  # (br, G*cw)
                 yyg = jax.lax.dynamic_slice_in_dim(
                     yyr, c0, G * cw)[None, :]
                 cols_ = jax.lax.dynamic_slice_in_dim(

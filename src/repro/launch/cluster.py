@@ -72,7 +72,7 @@ def main(argv=None) -> int:
         ndev = len(jax.devices())
         rows = max(int(ndev ** 0.5), 1)
         cols = max(ndev // rows, 1)
-        from repro.sharding.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((rows, cols), ("rows", "cols"),
                          devices=jax.devices()[: rows * cols])
         workers = rows * cols

@@ -38,6 +38,8 @@ def parse_buckets(spec: str) -> list[tuple[int, int]]:
 
 
 def main(argv=None) -> int:
+    from repro.runtime.xla_cache import use_persistent_cache
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--buckets", default="128x2,512x2",
                     help="comma list of NxD shape buckets")

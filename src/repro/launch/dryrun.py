@@ -135,8 +135,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     chips = math.prod(mesh.devices.shape)
     t0 = time.time()
     fn, args = build_cell(cfg, shape, mesh)
-    from repro.sharding.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import maybe_shard
-from repro.sharding.compat import get_abstract_mesh, shard_map
 from repro.models.layers.common import COMPUTE_DTYPE, PARAM_DTYPE, Params, Specs
 
 
@@ -160,7 +159,7 @@ def _moe_sharded(p: Params, x: jnp.ndarray, *, top_k: int,
     model_ext = mesh_axes["model"]
     data_axes = tuple(a for a in ("pod", "data") if a in mesh_axes)
     expert_parallel = e % model_ext == 0
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
 
     if expert_parallel:
         gspec = P("model", None, None)
@@ -196,7 +195,7 @@ def _moe_sharded(p: Params, x: jnp.ndarray, *, top_k: int,
         probs_out = probs.reshape(b, s, e)
         return y.reshape(b, s, d), aux, probs_out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), gspec, gspec, dspec),
         out_specs=(x_spec, P(), P(dd, None, None)),
@@ -209,7 +208,7 @@ def _moe_sharded(p: Params, x: jnp.ndarray, *, top_k: int,
 def moe_apply(p: Params, x: jnp.ndarray, *, top_k: int,
               capacity_factor: float = 1.25) -> MoEOut:
     """x: (B, S, D) -> (B, S, D). Dispatches on mesh context."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is not None and not mesh.empty and "model" in mesh.axis_names:
         return _moe_sharded(p, x, top_k=top_k,
                             capacity_factor=capacity_factor,

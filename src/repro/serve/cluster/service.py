@@ -835,7 +835,7 @@ class ClusterService:
             self.stats.batched_requests += max(len(live) - 1, 0)
         for i, r in enumerate(live):
             rbr, pref = slice_request(raw, i, r.n, self.config.stop)
-            result = finalize_raw(rbr, r.n, "serve_batched")
+            result = finalize_raw(rbr, r.n, solver.backend)
             gen = None
             if r.stream is not None:
                 gen = self._install_stream(r, result, pref)

@@ -1,11 +1,6 @@
-from repro.sharding.compat import (
-    get_abstract_mesh, make_abstract_mesh, make_mesh, pvary, set_mesh,
-    shard_map,
-)
 from repro.sharding.partitioning import (
     filter_spec, maybe_shard, shape_safe_shardings, tree_shardings,
 )
 
 __all__ = ["filter_spec", "maybe_shard", "shape_safe_shardings",
-           "tree_shardings", "get_abstract_mesh", "make_abstract_mesh",
-           "make_mesh", "pvary", "set_mesh", "shard_map"]
+           "tree_shardings"]

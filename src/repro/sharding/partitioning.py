@@ -22,8 +22,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.sharding.compat import get_abstract_mesh
-
 
 # ------------------------------------------------------ kd point partition
 def kd_median_cut(x: np.ndarray, leaf: int
@@ -88,11 +86,7 @@ def filter_spec(spec: P, axis_names) -> P:
         if isinstance(entry, str):
             return entry if entry in names else None
         kept = tuple(a for a in entry if a in names)
-        # unwrap singletons: jax 0.4.x PartitionSpec does not canonicalize
-        # ("a",) to "a", so P(("a",)) != P("a") there.
-        if len(kept) == 1:
-            return kept[0]
-        return kept if kept else None
+        return kept or None
 
     return P(*(keep(e) for e in spec))
 
@@ -101,7 +95,7 @@ def maybe_shard(x, spec: P):
     """with_sharding_constraint iff a mesh is in context (set_mesh /
     ``with mesh:``). Shape-safe: axes the array cannot divide are dropped
     per dim."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return x
     spec = filter_spec(spec, mesh.axis_names)

@@ -109,7 +109,8 @@ def _topk_run(data, cfg: SolveConfig) -> RawBackendResult:
                 preference=cfg.preference,
                 key=jax.random.PRNGKey(cfg.seed), config=cfg)
 
-    sweep_mode = topk_sharded.resolve_sweep(cfg.sweep, n=n)
+    sweep_mode = topk_sharded.resolve_sweep(cfg.sweep, n=n,
+                                            n_devices=cfg.device_count())
     if sweep_mode == "sharded":
         from repro.solver.engine import _prepare_mesh
         mesh, _ = _prepare_mesh("1d", cfg)
@@ -182,7 +183,8 @@ def _graph_run(data, cfg: SolveConfig) -> RawBackendResult:
     vals, idx = el.to_topk()
 
     mesh = None
-    if topk_sharded.resolve_sweep(cfg.sweep, n=n) == "sharded":
+    if topk_sharded.resolve_sweep(
+            cfg.sweep, n=n, n_devices=cfg.device_count()) == "sharded":
         from repro.solver.engine import _prepare_mesh
         mesh, _ = _prepare_mesh("1d", cfg)
         if mesh.shape["workers"] == 1:

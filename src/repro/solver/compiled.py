@@ -43,8 +43,8 @@ from repro.solver.config import SolveConfig
 PAD_NEG = -1.0e9
 
 #: orders the batched handle can run (``dense_fused``'s Pallas kernels are
-#: not vmap-batched; the service maps it to the numerically identical
-#: parallel order)
+#: not vmap-batched; the service maps it to the same-schedule parallel
+#: order and reports that order as each response's ``solve.backend``)
 _ORDERS = {"dense_sequential": "sequential", "dense_parallel": "parallel",
            "dense_fused": "parallel", "auto": "parallel"}
 
@@ -153,6 +153,11 @@ class BatchedDenseSolver:
         return jax.vmap(one)(s3b)
 
     # --------------------------------------------------------- lifecycle
+    @property
+    def backend(self) -> str:
+        """The dense backend whose sweep order this handle runs."""
+        return f"dense_{self.order}"
+
     @property
     def compiled(self) -> bool:
         return self._solve_exec is not None

@@ -95,14 +95,15 @@ class SolveConfig:
     # "auto" picks sharded on multi-device hosts once N >= SHARDED_SWEEP_N.
     sweep: str = "auto"            # auto|single|sharded
     # column-statistics exchange for the sharded sweep: "allgather"
-    # reproduces the single-device scatter order bit-for-bit (O(N*k)
+    # reproduces the single-device scatter order (O(N*k)
     # gathered per level); "psum" all-reduces O(N) per-shard partial
     # column sums — the scalable mode, exact exemplar sets but
     # float-associativity ulps vs the oracle. "auto" = allgather until
     # the edge list outgrows ALLGATHER_MAX_ELEMS, then psum.
     exchange: str = "auto"         # auto|allgather|psum
 
-    # distributed backends (mr1d_*, mr2d)
+    # distributed backends (mr1d_*, mr2d, sharded dense_topk); also the
+    # device count the auto rules plan for (see device_count)
     mesh: Optional[Any] = None          # jax Mesh; auto-built when None
     pad_to: Optional[int] = None        # force-pad N to a multiple (tests)
 
@@ -144,7 +145,7 @@ class SolveConfig:
     # that many sweeps; for coarsen, per-stage artifacts every that many
     # local batch groups plus one after the global solve, so a stage-3
     # crash resumes at stage 3. resume_from restarts from the newest
-    # checkpoint in that directory, bit-exact with the uninterrupted
+    # checkpoint in that directory, exact with the uninterrupted
     # solve (same exemplars, same trace tail); the run's config/shape
     # key is validated against the checkpoint's sidecar metadata.
     checkpoint_every: int = 0
@@ -161,3 +162,12 @@ class SolveConfig:
 
     def replace(self, **kw) -> "SolveConfig":
         return dataclasses.replace(self, **kw)
+
+    def device_count(self) -> int:
+        """Devices the auto rules plan for: the caller's ``mesh`` when
+        one is given (a one-device mesh pins the single-device paths on a
+        multi-chip host), else every visible device."""
+        if self.mesh is not None:
+            return self.mesh.size
+        import jax
+        return len(jax.devices())

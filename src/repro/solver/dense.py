@@ -9,8 +9,8 @@ Two pieces:
   stay as jnp reductions — they read the same tensors the kernels just
   streamed and are not the bottleneck. Matches
   ``hap_sweep_parallel`` numerically (same formulas, same tie rules; the
-  kernel's tiled column sums can differ from XLA's reduction order by
-  float-associativity ulps, which never moves an argmax on real data).
+  kernel's tiled column sums can differ from ``hap.column_sum``'s fixed
+  order by float-associativity ulps).
 
 * ``run_dense`` — the jitted driver the engine calls for the whole dense
   family (``dense_sequential``, ``dense_parallel``, ``dense_fused``).
@@ -117,8 +117,8 @@ def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
     """
     e0 = jnp.full((levels, n), -1, jnp.int32)
     if axis_name is not None:
-        from repro.sharding.compat import pvary
-        e0 = pvary(e0, (axis_name,))    # match assign()'s device-varying type
+        # match assign()'s device-varying type
+        e0 = jax.lax.pcast(e0, (axis_name,), to="varying")
 
     def count_changes(e, e_prev):
         diff = e != e_prev

@@ -202,8 +202,9 @@ def _prepare_mesh(kind, cfg: SolveConfig):
     ``mesh_kind`` — the sharded top-k build and sweep drivers pass the
     string directly (they shard rows over a 1-D worker mesh without
     being registered mesh backends themselves)."""
-    from repro.launch.mesh import make_worker_mesh
-    from repro.sharding.compat import make_mesh, maybe_init_distributed
+    from repro.launch.mesh import (
+        make_mesh, make_worker_mesh, maybe_init_distributed,
+    )
 
     # multi-process launches (env-var-described) must join the cluster
     # before the first mesh is built so jax.devices() spans every host;
@@ -260,7 +261,7 @@ def solve(data, config: Optional[SolveConfig] = None,
     backend = cfg.backend
     if backend == "auto":
         backend = auto_select(
-            n, cfg.levels, n_devices=len(jax.devices()),
+            n, cfg.levels, n_devices=cfg.device_count(),
             has_points=x is not None, platform=jax.default_backend(),
             cfg=cfg, has_edges=el is not None)
     spec = get_backend(backend)

@@ -196,9 +196,20 @@ def make_topk_sweep(idx: jnp.ndarray, *, damping: float, kappa: float,
 
     One definition shared by ``run_topk`` and the checkpointed segment
     runner (``repro.solver.checkpointing``) — both must execute the
-    identical op sequence per sweep for resume to be bit-exact."""
+    identical op sequence per sweep for resume to be bit-exact.
+
+    The two updates that scatter and gather through ``idx`` (tau, alpha)
+    run level by level (L is small and static: unrolled) rather than
+    under ``vmap``: a vmapped scatter puts the level axis minor, (N*kk,
+    L), which a TPU pads from L to 128 lanes (40x the state at N=2^18)."""
+    def tau_red(r, c):                       # (L-1, N, kk), (L-1, N)
+        if r.shape[0] == 0:                  # L=1: no level above
+            return jnp.zeros(c.shape, c.dtype)
+        return jnp.stack([tau_topk(r[l], c[l], idx)
+                          for l in range(r.shape[0])])
+
     reducers = hap.SweepReducers(
-        tau=jax.vmap(lambda r, c: tau_topk(r, c, idx)),
+        tau=tau_red,
         phi=jax.vmap(phi_topk),
         c=jax.vmap(c_topk),
         s_next=lambda s_up, a, r, kap, mode: jax.vmap(
@@ -209,9 +220,9 @@ def make_topk_sweep(idx: jnp.ndarray, *, damping: float, kappa: float,
         return hap._damp(r, jax.vmap(rho_topk)(s, a, tau), damping)
 
     def update_a(r, c, phi, a):
-        return hap._damp(
-            a, jax.vmap(lambda rl, cl, pl: alpha_topk(rl, cl, pl, idx))(
-                r, c, phi), damping)
+        return hap._damp(a, jnp.stack([
+            alpha_topk(r[l], c[l], phi[l], idx)
+            for l in range(r.shape[0])]), damping)
 
     def sweep(state, it):
         return hap.jacobi_sweep(

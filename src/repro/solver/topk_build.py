@@ -17,8 +17,8 @@ This module is its front door now:
   worker count because the build is embarrassingly row-parallel.
 
 Every path produces the identical edge set (value desc, col asc
-tie-break; ``tests/test_topk_build.py`` holds them bit-equal), so the
-backend knob is purely a throughput choice.
+tie-break; ``tests/test_topk_build.py`` holds them to it, values within
+the numeric contract), so the backend knob is purely a throughput choice.
 """
 from __future__ import annotations
 
@@ -32,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.topk_similarity import (
     SELECT_EXACT_MAX_N, kd_order, topk_similarity, topk_similarity_twostage,
 )
-from repro.sharding.compat import shard_map
 from repro.solver.config import SolveConfig
 
 #: every registered build backend; "auto" resolves to one of the rest
@@ -100,7 +99,7 @@ def _local_build(x, k, cfg: SolveConfig, backend: str, *,
                 block_cols=min(cfg.build_block_cols, 1024))
         except Exception as exc:  # noqa: BLE001 — degrade, don't fail
             # a platform that rejects the Pallas build falls back to the
-            # reference scan — bit-identical edge set, just slower
+            # reference scan — the same edge set, just slower
             from repro.runtime import degrade
             degrade.record("build.fused", "reference", exc)
             return topk_similarity(
@@ -128,12 +127,12 @@ def sharded_topk_similarity(
     Rows are padded to a worker multiple and partitioned; the column set
     (and, for a two-stage inner build, the host-computed kd permutation)
     is replicated, so each worker's output block is exactly its rows'
-    edge lists. Bit-identical to the single-device builds.
+    edge lists: the single-device builds' edges.
 
     On a one-device mesh this degenerates to the inner build plus pure
     overhead (shard_map dispatch, the replicated column copy — measured
     6x slower at N = 2048), so it short-circuits straight to the inner
-    build there; the output is bit-identical either way.
+    build there; the output is the same either way.
     """
     if mesh is None:
         from repro.solver.engine import _prepare_mesh
@@ -162,7 +161,7 @@ def sharded_topk_similarity(
             perm=perm_ if inner == "twostage" else None)
 
     with mesh:
-        vals, idx = shard_map(
+        vals, idx = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P("workers", None), P(None, None), P(None)),
             out_specs=(P("workers", None), P("workers", None)))(
@@ -175,7 +174,8 @@ def build_topk_similarity(x: jnp.ndarray, k: int, cfg: SolveConfig
     """The build front door ``repro.solver.topk`` calls: resolve the
     backend knob, run it, return the compressed off-diagonal layout."""
     n = int(x.shape[0])
-    backend = resolve_build_backend(cfg.build, n=n, k=k, metric=cfg.metric)
+    backend = resolve_build_backend(cfg.build, n=n, k=k, metric=cfg.metric,
+                                    n_devices=cfg.device_count())
     if backend == "sharded":
         return sharded_topk_similarity(x, k, cfg)
     return _local_build(x, k, cfg, backend)

@@ -20,9 +20,10 @@ Per-sweep dataflow on each worker (B = N/W local rows):
 
   ``allgather`` — workers all-gather the (B, k+1) rho blocks and re-run
   the oracle's own scatter over the full edge set. Accumulation order is
-  identical to the single-device scatter, so the sharded sweep is
-  **bit-exact** against ``run_topk`` (trace included). O(N*k) gathered
-  per level per sweep.
+  identical to the single-device scatter, so the sharded sweep matches
+  ``run_topk``'s exemplars and trace exactly, its messages within the
+  numeric contract (docs/solver.md). O(N*k) gathered per level per
+  sweep.
 
   ``psum`` — each worker scatters its rows' contributions into a
   full-length (N,) partial and the partials are all-reduced. O(N)
@@ -45,7 +46,7 @@ N is padded to the worker multiple with inert dummy rows
 at the dummy itself with strongly repelling values, so real columns
 never receive a dummy contribution and the decode pins dummies to
 themselves. Multi-process launches (one process per host) work through
-``sharding.compat.maybe_init_distributed`` + a process-spanning
+``launch.mesh.maybe_init_distributed`` + a process-spanning
 ``workers`` mesh built from the global device list.
 """
 from __future__ import annotations
@@ -62,7 +63,6 @@ from repro.kernels.topk_ops import (
     alpha_from_stats, assignments_topk, c_topk, col_partial_topk,
     col_stats_topk, phi_topk, rho_topk, s_next_topk, tau_from_stats,
 )
-from repro.sharding.compat import pvary, shard_map
 from repro.sharding.partitioning import device_put_row_sharded
 from repro.solver import dense
 from repro.solver.topk import TopKState
@@ -81,7 +81,7 @@ EXCHANGE_MODES = ("auto", "allgather", "psum")
 #: zero-communication single-device loop.
 SHARDED_SWEEP_N = 32768
 
-#: padded edge count (N * (k+1)) above which the bit-exact allgather
+#: padded edge count (N * (k+1)) above which the allgather
 #: exchange's O(N*k) per-worker gather buffers would dominate the very
 #: state the sharding removed; "auto" switches to the O(N) psum
 #: exchange there (16M edges ~ 64 MB gathered per level).
@@ -234,7 +234,7 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
         init = hap.hap_init(s_loc)
         # tau/phi/c come out of hap_init as fresh constants; the loop
         # carries device-varying replacements, so mark them up front.
-        vary = lambda x: pvary(x, (AXIS,))
+        vary = lambda x: jax.lax.pcast(x, (AXIS,), to="varying")
         init = init._replace(tau=vary(init.tau), phi=vary(init.phi),
                              c=vary(init.c))
         scal = lambda v: vary(jnp.reshape(v, (1,)))
@@ -248,7 +248,7 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
 
         # segment variant: rest = (until[, carry...]). stable/it/trace
         # stay device-invariant through the loop (the change counter is
-        # psum-ed), so the replicated carry inputs match without pvary.
+        # psum-ed), so the replicated carry inputs match without a pcast.
         until = rest[0][0]
         if with_carry:
             c_state, c_e, c_stable, c_it, c_trace = rest[1:]
@@ -271,7 +271,7 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
         in_specs.append(P(None))                   # until
         if with_carry:
             in_specs += [state_spec, row2, P(None), P(None), P(None)]
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(state_spec, row2, P(AXIS), P(AXIS), P(AXIS, None))))
@@ -296,9 +296,9 @@ def run_topk_sharded(
     Same return contract as ``run_topk`` —
     ``(TopKState, exemplars, n_sweeps, converged, trace)`` — with
     exemplars/state in the padded N' (the engine strips dummies);
-    assignments match the single-device oracle (bit-exactly under the
-    ``allgather`` exchange) and ``stop="converged"`` exits on the same
-    sweep with the same trace.
+    assignments match the single-device oracle (under the ``allgather``
+    exchange, which keeps its scatter order) and ``stop="converged"``
+    exits on the same sweep with the same trace.
     """
     if tuple(mesh.axis_names) != (axis_name,):
         raise ValueError(

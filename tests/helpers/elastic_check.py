@@ -17,7 +17,7 @@ from repro.data.pipeline import synthetic_token_stream
 from repro.models import Mode, model_init
 from repro.runtime.elastic import reshard_state
 from repro.sharding import shape_safe_shardings
-from repro.sharding.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.train.loop import (
     init_train_state, make_train_step, train_state_specs,
 )
@@ -45,7 +45,7 @@ def main() -> int:
     mesh1 = mesh_of((2, 2))
     sds = jax.eval_shape(lambda: state)
     shard1 = shape_safe_shardings(mesh1, sds, state_specs)
-    with set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         st = reshard_state(state, state_specs, mesh1)
         fn = jax.jit(step, in_shardings=(shard1, None),
                      out_shardings=(shard1, None))
@@ -62,7 +62,7 @@ def main() -> int:
     # ---- phase 2: "pod lost": restore onto 2 devices (1 data x 2 model)
     mesh2 = mesh_of((1, 2))
     _, restored = CheckpointManager(ckdir).restore_latest(state)
-    with set_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         st2 = reshard_state(restored, state_specs, mesh2)
         shard2 = shape_safe_shardings(mesh2, jax.eval_shape(lambda: state),
                                       state_specs)

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers.moe import _moe_dense, moe_apply, moe_init
-from repro.sharding.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 
 
 def main() -> int:
@@ -22,7 +22,7 @@ def main() -> int:
         x = jax.random.normal(key, (4, 16, 32), jnp.float32) * 0.5
         dense = _moe_dense(p, x, top_k=2, capacity_factor=8.0)
         mesh = make_mesh((2, 2), ("data", "model"))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             sh = jax.jit(lambda p, x: moe_apply(
                 p, x, top_k=2, capacity_factor=8.0))(p, x)
         dy = float(jnp.max(jnp.abs(sh.y - dense.y)))

@@ -17,7 +17,7 @@ from repro.core import (
 from repro.core.mrhap import run_mrhap_2d
 from repro.core.preferences import median_preference
 from repro.data import gaussian_blobs
-from repro.sharding.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 
 def main() -> int:

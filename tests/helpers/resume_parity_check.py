@@ -5,10 +5,11 @@ N=1000 does not divide 8 workers, so the checkpointed sharded run pads
 with inert dummy rows; checkpoints store the *unpadded logical* state
 and resume re-pads it, which this check exercises against two oracles:
 
-* an uninterrupted single-device ``run_topk`` run — bit-exact exemplars,
-  full message state, per-sweep trace;
+* an uninterrupted single-device ``run_topk`` run — exact exemplars,
+  sweep count and per-sweep trace, full message state within the numeric
+  contract (``parity.py``);
 * a crash (injected at the second segment boundary via
-  ``repro.runtime.faultinject``) + resume — bit-exact again, and the
+  ``repro.runtime.faultinject``) + resume — the same contract, and the
   resumed run fires strictly fewer segment boundaries than a fresh run
   (proof it restored state instead of recomputing).
 
@@ -25,6 +26,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+from parity import assert_values_close
 
 from repro.launch.mesh import make_worker_mesh
 from repro.runtime import faultinject
@@ -66,10 +68,8 @@ def main() -> int:
             assert bool(conv) == bool(o_conv), tag
             np.testing.assert_array_equal(np.asarray(trace),
                                           np.asarray(o_trace))
-            jax.tree.map(
-                lambda a, b: np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b)), state, o_state)
-            print(f"{tag}: bit-exact vs single-device oracle "
+            jax.tree.map(assert_values_close, state, o_state)
+            print(f"{tag}: matches the single-device oracle "
                   f"(sweeps={int(n_sweeps)})")
 
         # uninterrupted checkpointed sharded run

@@ -1,7 +1,8 @@
 """Subprocess helper: sharded top-k build parity on 8 forced host
 devices. N=1000 does not divide 8 workers evenly once rows are padded to
-the mesh — the driver must pad, build per worker, and strip, staying
-bit-identical to the single-device reference and two-stage builds. Also
+the mesh — the driver must pad, build per worker, and strip, selecting
+the single-device reference build's edges with values within the
+numeric contract (``parity.py``). Also
 runs the full dense_topk solve through build='sharded'. Exits nonzero on
 any mismatch."""
 import os
@@ -12,6 +13,7 @@ import sys
 
 import jax.numpy as jnp
 import numpy as np
+from parity import values_close
 
 from repro.data import gaussian_blobs
 from repro.kernels.topk_similarity import topk_similarity
@@ -31,9 +33,9 @@ def main() -> int:
     for inner in ("reference", "twostage"):
         v, i = sharded_topk_similarity(xj, k, SolveConfig(), mesh=mesh,
                                        inner=inner)
-        same = (np.array_equal(np.asarray(v), np.asarray(vr))
-                and np.array_equal(np.asarray(i), np.asarray(ir)))
-        print(f"sharded[{inner}] x 8 workers: bit_exact={same}")
+        same = (np.array_equal(np.asarray(i), np.asarray(ir))
+                and values_close(v, vr))
+        print(f"sharded[{inner}] x 8 workers: edges_equal={same}")
         ok &= same
 
     ref = solve(x, backend="dense_topk", k=k, levels=2, max_iterations=15,

@@ -7,9 +7,10 @@ rows; the input is duplicate-heavy (exact duplicate points produce tied
 col asc) tie-break across shard boundaries). Checked against the
 single-device ``run_topk`` oracle:
 
-* ``exchange="allgather"``: bit-exact exemplars, full message state
-  (s/r/a/tau/phi/c), and per-sweep trace, for both stopping rules;
-  ``stop="converged"`` exits on the same sweep with the same flag.
+* ``exchange="allgather"``: exact exemplars and per-sweep trace, the
+  full message state (s/r/a/tau/phi/c) within the numeric contract
+  (``parity.py``), for both stopping rules; ``stop="converged"`` exits
+  on the same sweep with the same flag.
 * ``exchange="psum"``: identical exemplar sets per level (documented
   float-associativity tolerance on the messages), same converged sweep.
 * the ``solve()`` front door with ``sweep="sharded"`` equals
@@ -25,6 +26,7 @@ import sys
 
 import jax.numpy as jnp
 import numpy as np
+from parity import state_close
 
 from repro.launch.mesh import make_worker_mesh
 from repro.solver import solve
@@ -44,13 +46,6 @@ def duplicate_heavy_points(n: int, seed: int = 4) -> np.ndarray:
     return x          # second half: exact duplicates of the 5 centers
 
 
-def state_equal(a, b, n: int) -> bool:
-    return all(
-        np.array_equal(np.asarray(getattr(a, f)),
-                       np.asarray(getattr(b, f))[:, :n])
-        for f in ("s", "r", "a", "tau", "phi", "c"))
-
-
 def main() -> int:
     x = duplicate_heavy_points(N)
     s3k, idx = build_from_points(jnp.asarray(x), K, LEVELS)
@@ -66,13 +61,14 @@ def main() -> int:
         st2, e2, ns2, conv2, tr2 = run_topk_sharded(
             s3k, idx, mesh, max_iterations=40, damping=0.7, stop=stop,
             patience=5, exchange="allgather")
-        bit = (np.array_equal(e, np.asarray(e2)[:, :N])
-               and np.array_equal(tr, np.asarray(tr2))
-               and int(ns) == int(ns2) and bool(conv) == bool(conv2)
-               and state_equal(st.hap, st2.hap, N))
-        print(f"[{stop}] allgather x 8 workers: bit_exact={bit} "
+        exact = (np.array_equal(e, np.asarray(e2)[:, :N])
+                 and np.array_equal(tr, np.asarray(tr2))
+                 and int(ns) == int(ns2) and bool(conv) == bool(conv2))
+        close = state_close(st.hap, st2.hap, N)
+        print(f"[{stop}] allgather x 8 workers: exemplars_trace_exact="
+              f"{exact} state_within_contract={close} "
               f"(sweeps {int(ns)} vs {int(ns2)})")
-        ok &= bit
+        ok &= exact and close
 
         st3, e3, ns3, conv3, _ = run_topk_sharded(
             s3k, idx, mesh, max_iterations=40, damping=0.7, stop=stop,

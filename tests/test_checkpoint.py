@@ -53,7 +53,7 @@ def test_mesh_agnostic_restore_via_elastic(tmp_path):
     t = _tree()
     save_tree(str(tmp_path / "ck"), t)
     back = restore_tree(str(tmp_path / "ck"), t)
-    from repro.sharding.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("data",))
     specs = {"a": P("data", None), "nested": {"b": P(None), "c": P()}}
     placed = reshard_state(back, specs, mesh)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers.parity import assert_values_close
 from repro.data import gaussian_blobs
 from repro.launch.mesh import make_worker_mesh
 from repro.runtime import faultinject
@@ -88,10 +89,12 @@ def test_resume_skips_completed_sweeps(tmp_path):
 
 # ------------------------------------------------- dense_topk (sharded)
 def test_sharded_crash_resume_bit_exact(tmp_path):
-    """The sharded sweep program checkpoints/resumes bit-exact against
-    the single-device oracle (driven directly so a 1-device host still
-    exercises the shard_map program; the 8-device variant is nightly —
-    tests/helpers/resume_parity_check.py)."""
+    """The sharded sweep program checkpoints/resumes to the
+    single-device oracle's exemplars, sweep count and trace exactly, and
+    its message state within the numeric contract (tests/helpers/
+    parity.py) — driven directly so a 1-device host still exercises the
+    shard_map program; the 8-device variant is
+    tests/helpers/resume_parity_check.py."""
     x = _pts(n=96)
     cfg = SolveConfig(k=12, stop="converged", max_iterations=25,
                       patience=5, damping=0.7, preference="median",
@@ -117,8 +120,7 @@ def test_sharded_crash_resume_bit_exact(tmp_path):
     np.testing.assert_array_equal(np.asarray(e), np.asarray(o_e))
     assert int(n_sweeps) == int(o_sweeps) and bool(conv) == bool(o_conv)
     np.testing.assert_array_equal(np.asarray(trace), np.asarray(o_trace))
-    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
-        np.asarray(a), np.asarray(b)), state, o_state)
+    jax.tree.map(assert_values_close, state, o_state)
 
 
 # -------------------------------------------------------------- coarsen

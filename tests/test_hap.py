@@ -8,8 +8,8 @@ from repro.core import (
     stack_levels,
 )
 from repro.core.hap import (
-    alpha_update, c_update, hap_init, phi_from_level, rho_update,
-    tau_from_level,
+    alpha_update, c_update, column_sum, hap_init, phi_from_level,
+    rho_update, tau_from_level,
 )
 from repro.core.preferences import median_preference
 from repro.data import gaussian_blobs
@@ -48,6 +48,23 @@ def test_alpha_with_zero_c_phi_matches_flat():
     z = jnp.zeros((10,))
     np.testing.assert_allclose(np.asarray(alpha_update(r, z, z)),
                                np.asarray(availability_update(r)), atol=1e-6)
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3, 384, 1000])
+def test_column_sum_is_order_fixed(rows):
+    """column_sum sums in one order whatever the program: trailing zero
+    rows (a bucket-padded request) and a vmapped batch give the same
+    bits as the plain call, and the value is the column sum."""
+    x = np.random.default_rng(rows).standard_normal((rows, 7)).astype(
+        np.float32)
+    got = np.asarray(jax.jit(column_sum)(x))
+    np.testing.assert_allclose(got, x.astype(np.float64).sum(axis=0),
+                               rtol=1e-5, atol=1e-5)
+    padded = np.concatenate([x, np.zeros((rows + 5, 7), np.float32)])
+    np.testing.assert_array_equal(np.asarray(jax.jit(column_sum)(padded)),
+                                  got)
+    batched = jax.jit(jax.vmap(column_sum))(np.stack([padded, 2 * padded]))
+    np.testing.assert_array_equal(np.asarray(batched)[0], got)
 
 
 def test_tau_equation_manual():

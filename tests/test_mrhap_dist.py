@@ -15,7 +15,7 @@ from repro.core import (
 )
 from repro.core.mrhap import run_mrhap_2d
 from repro.core.preferences import median_preference
-from repro.sharding.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.data import gaussian_blobs
 
 HELPER = os.path.join(os.path.dirname(__file__), "helpers",

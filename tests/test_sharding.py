@@ -4,14 +4,14 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import compat
+from repro.launch.mesh import make_mesh
 from repro.sharding.partitioning import (
     _divisible_spec, filter_spec, maybe_shard, shape_safe_shardings,
 )
 
 
 def _mesh():
-    return compat.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def test_filter_spec_drops_missing_axes():
@@ -23,13 +23,13 @@ def test_filter_spec_drops_missing_axes():
 
 
 def test_divisible_spec_drops_indivisible():
-    mesh = compat.make_abstract_mesh((2,), ("data",))
+    mesh = jax.sharding.AbstractMesh((2,), ("data",))
     assert _divisible_spec(P("data"), (3,), mesh) == P(None)
     assert _divisible_spec(P("data"), (4,), mesh) == P("data")
 
 
 def test_divisible_spec_tuple_prefix():
-    mesh = compat.make_abstract_mesh((2, 2), ("a", "b"))
+    mesh = jax.sharding.AbstractMesh((2, 2), ("a", "b"))
     # dim 2: only the first axis of ("a","b") fits
     assert _divisible_spec(P(("a", "b")), (2,), mesh) == P("a")
     assert _divisible_spec(P(("a", "b")), (4,), mesh) == P(("a", "b"))

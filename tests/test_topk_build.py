@@ -3,18 +3,20 @@ edge set (docs/solver.md "similarity build").
 
 Contracts:
 
-* bit-parity of the two-stage (threshold-gated) build, the fused Pallas
-  kernel (interpret mode on this CPU container), and the sharded driver
-  against the reference scan AND the dense compression oracle
-  (``topk_from_dense``) — odd N, non-divisor tile shapes, k past the
-  tile row count, and full coverage (k = N-1) included;
+* parity of the two-stage (threshold-gated) build, the fused Pallas
+  kernel (interpret mode on CPU), and the sharded driver against the
+  reference scan AND the dense compression oracle (``topk_from_dense``)
+  under the numeric contract (``tests/helpers/parity.py``: identical
+  edge index sets, values within tolerance) — odd N, non-divisor tile
+  shapes, k past the tile row count, and full coverage (k = N-1)
+  included;
 * tie-break determinism: duplicate similarity values (duplicated points)
   select the same edges on every path at any tile shape — the
   (value desc, col asc) contract that keeps k = N-1 parity meaningful;
 * the build backend knob threads through ``SolveConfig``/``solve()`` and
   is validated at the front door;
-* the sharded driver is bit-exact on a 1-device mesh here and on a real
-  8-worker mesh in the nightly slow tier (subprocess helper).
+* the sharded driver holds the same contract on a 1-device mesh here
+  and on a real 8-worker mesh in the slow tier (subprocess helper).
 """
 import os
 import subprocess
@@ -24,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers.parity import assert_values_close
 from repro.core.similarity import pairwise_similarity
 from repro.data import gaussian_blobs
 from repro.kernels.topk_build_fused import topk_similarity_fused
@@ -43,6 +46,13 @@ def _assert_same(a, b):
     np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
 
 
+def _assert_edges(a, b):
+    """The numeric contract on an edge list: identical column index
+    sets, similarity values within tolerance."""
+    np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
+    assert_values_close(a[0], b[0])
+
+
 # ------------------------------------------------------------ bit parity
 @pytest.mark.parametrize("n,d,k,seed", [
     (97, 3, 9, 0),       # odd N
@@ -55,13 +65,13 @@ def test_all_builds_match_dense_oracle(n, d, k, seed):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
     oracle = topk_from_dense(pairwise_similarity(x), k)
-    _assert_same(topk_similarity(x, k, block_rows=16, block_cols=24),
-                 oracle)
-    _assert_same(topk_similarity_twostage(x, k, block_rows=32, chunk=16,
-                                          round_chunks=3, max_rounds=2,
-                                          residual_chunks=4), oracle)
-    _assert_same(topk_similarity_fused(x, k, block_rows=16,
-                                       block_cols=32), oracle)
+    _assert_edges(topk_similarity(x, k, block_rows=16, block_cols=24),
+                  oracle)
+    _assert_edges(topk_similarity_twostage(x, k, block_rows=32, chunk=16,
+                                           round_chunks=3, max_rounds=2,
+                                           residual_chunks=4), oracle)
+    _assert_edges(topk_similarity_fused(x, k, block_rows=16,
+                                        block_cols=32), oracle)
 
 
 @pytest.mark.parametrize("br,bc", [(16, 24), (97, 97), (8, 8), (32, 130),
@@ -89,14 +99,14 @@ def test_tiebreak_identical_under_duplicates(br, bc):
 @pytest.mark.parametrize("metric", ["neg_euclidean", "cosine"])
 def test_twostage_other_metrics(metric):
     """The two-stage gate runs in (normalized) squared-distance space but
-    the survivor values use the metric's own formula — outputs stay
-    bit-equal to the reference scan."""
+    the survivor values use the metric's own formula — the same edges as
+    the reference scan, values within the numeric contract."""
     x = jnp.asarray(np.random.default_rng(7)
                     .standard_normal((150, 4)).astype(np.float32))
     ref = topk_similarity(x, 12, metric=metric, block_rows=32,
                           block_cols=48)
-    _assert_same(topk_similarity_twostage(x, 12, metric=metric, chunk=16),
-                 ref)
+    _assert_edges(topk_similarity_twostage(x, 12, metric=metric, chunk=16),
+                  ref)
 
 
 def test_select_exact_orders_ties_by_column():

@@ -2,10 +2,11 @@
 
 Contracts:
 
-* ``run_topk_sharded`` on a degenerate 1-worker mesh is bit-exact
-  against the single-device ``run_topk`` oracle — exemplars, full
-  message state, trace, and the converged-stop sweep count — for both
-  exchanges and both stopping rules (the real 8-worker parity check,
+* ``run_topk_sharded`` on a degenerate 1-worker mesh matches the
+  single-device ``run_topk`` oracle under the numeric contract
+  (``tests/helpers/parity.py``): exemplars, trace, and the converged-stop
+  sweep count exactly, the full message state within tolerance — for
+  both exchanges and both stopping rules (the real 8-worker parity check,
   including duplicate-heavy tie-breaks across shard boundaries, runs in
   the nightly slow tier via ``tests/helpers/topk_sweep_dist_check.py``);
 * padding inserts inert dummy rows (self-pointing edges, repelling
@@ -23,8 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers.parity import assert_values_close
 from repro.launch.mesh import make_worker_mesh
-from repro.sharding.compat import maybe_init_distributed
+from repro.launch.mesh import maybe_init_distributed
 from repro.solver import solve
 from repro.solver.topk import build_from_points, run_topk
 from repro.solver.topk_sharded import (
@@ -47,7 +49,8 @@ def _dup_points(n=150, seed=3):
 @pytest.mark.parametrize("exchange", ["allgather", "psum"])
 def test_single_worker_mesh_bit_exact(stop, exchange):
     """W=1 runs the full shard_map program (identity collectives); both
-    exchanges must reproduce the oracle bit-for-bit there."""
+    exchanges must reproduce the oracle's exemplars, trace and stop
+    exactly, and its message state within the numeric contract."""
     s3k, idx = build_from_points(jnp.asarray(_dup_points()), 12, 3)
     st, e, ns, conv, tr = run_topk(
         s3k, idx, max_iterations=25, damping=0.7, stop=stop, patience=5)
@@ -59,9 +62,8 @@ def test_single_worker_mesh_bit_exact(stop, exchange):
     np.testing.assert_array_equal(np.asarray(tr), np.asarray(tr2))
     assert int(ns) == int(ns2) and bool(conv) == bool(conv2)
     for f in ("s", "r", "a", "tau", "phi", "c"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(st.hap, f)),
-            np.asarray(getattr(st2.hap, f))[:, :n])
+        assert_values_close(np.asarray(getattr(st2.hap, f))[:, :n],
+                            getattr(st.hap, f), err_msg=f)
 
 
 def test_levels_1_edge_case():
@@ -139,7 +141,7 @@ def test_invalid_knobs_rejected_at_entry():
 
 
 def test_non_worker_mesh_rejected():
-    from repro.sharding.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     s3k, idx = build_from_points(jnp.asarray(_dup_points(40)), 5, 2)
     bad = make_mesh((1, 1), ("rows", "cols"))
     with pytest.raises(ValueError, match="1-D mesh"):
