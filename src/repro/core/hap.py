@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.affinity import masked_top2
+from repro.runtime import trace
 
 SweepOrder = Literal["sequential", "parallel"]
 SUpdateMode = Literal["off", "paper", "evidence"]
@@ -221,23 +222,31 @@ def jacobi_sweep(
 
     # --- Job 1 ---------------------------------------------------------
     # tau^{l+1} from level l's previous-iteration rho/c; tau[0] stays +inf.
-    tau_new = red.tau(r[:-1], c[:-1])                           # (L-1, N)
-    tau_new = jnp.concatenate([tau[:1], tau_new], axis=0)
-    c_new = red.c(a, r)                                         # (L, N)
+    with jax.named_scope(trace.SCOPE_TAU):
+        tau_new = red.tau(r[:-1], c[:-1])                       # (L-1, N)
+        tau_new = jnp.concatenate([tau[:1], tau_new], axis=0)
+    with jax.named_scope(trace.SCOPE_C):
+        c_new = red.c(a, r)                                     # (L, N)
     keep = jnp.asarray(first_iter)
-    tau = jnp.where(keep, tau, tau_new)
-    c = jnp.where(keep, c, c_new)
-    r = update_r(s, a, tau, r)
+    with jax.named_scope(trace.SCOPE_TAU):
+        tau = jnp.where(keep, tau, tau_new)
+    with jax.named_scope(trace.SCOPE_C):
+        c = jnp.where(keep, c, c_new)
+    with jax.named_scope(trace.SCOPE_RHO):
+        r = update_r(s, a, tau, r)
 
     # --- Job 2 ---------------------------------------------------------
     # phi^{l-1} from level l's alpha (previous iteration); phi[L-1] stays 0.
-    phi_new = red.phi(a[1:], s[1:])                             # (L-1, N)
-    phi = jnp.concatenate([phi_new, phi[-1:]], axis=0)
-    a = update_a(r, c, phi, a)
+    with jax.named_scope(trace.SCOPE_PHI):
+        phi_new = red.phi(a[1:], s[1:])                         # (L-1, N)
+        phi = jnp.concatenate([phi_new, phi[-1:]], axis=0)
+    with jax.named_scope(trace.SCOPE_ALPHA):
+        a = update_a(r, c, phi, a)
 
     if s_mode != "off":
-        s_upd = red.s_next(s[1:], a[:-1], r[:-1], kappa, s_mode)
-        s = jnp.concatenate([s[:1], s_upd], axis=0)
+        with jax.named_scope(trace.SCOPE_S_NEXT):
+            s_upd = red.s_next(s[1:], a[:-1], r[:-1], kappa, s_mode)
+            s = jnp.concatenate([s[:1], s_upd], axis=0)
     return HAPState(s, r, a, tau, phi, c)
 
 

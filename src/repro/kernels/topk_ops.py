@@ -21,9 +21,11 @@ genuinely sparse primitive in the sweep.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.affinity import masked_top2
+from repro.runtime import trace
 
 NEG_INF = float("-inf")
 
@@ -52,8 +54,10 @@ def col_partial_topk(r: jnp.ndarray, idx: jnp.ndarray,
     (or all-gathers rho and scatters the full edge set at once — the
     allgather exchange, same accumulation order as this single scatter).
     """
-    rp = jnp.maximum(r, 0.0).at[:, 0].set(0.0)      # self slot excluded
-    return jnp.zeros((n_total,), r.dtype).at[idx.ravel()].add(rp.ravel())
+    with jax.named_scope(trace.SCOPE_COLSUM):
+        rp = jnp.maximum(r, 0.0).at[:, 0].set(0.0)  # self slot excluded
+        return jnp.zeros((n_total,), r.dtype).at[idx.ravel()].add(
+            rp.ravel())
 
 
 def col_stats_topk(r: jnp.ndarray, idx: jnp.ndarray
@@ -80,13 +84,14 @@ def alpha_from_stats(r: jnp.ndarray, idx: jnp.ndarray, col: jnp.ndarray,
     identical arithmetic either way (the self-slot gather is an identity
     gather on one device).
     """
-    base_j = base[idx]
-    col_j = col[idx]
-    rp = jnp.maximum(r, 0.0)
-    a_off = jnp.minimum(0.0, base_j + rdiag[idx] + col_j - rp)
-    rows = idx[:, 0]                                 # global row per block row
-    a_self = base[rows] + col[rows]                  # diagonal rule, no clamp
-    return a_off.at[:, 0].set(a_self)
+    with jax.named_scope(trace.SCOPE_GATHER):
+        base_j = base[idx]
+        col_j = col[idx]
+        rp = jnp.maximum(r, 0.0)
+        a_off = jnp.minimum(0.0, base_j + rdiag[idx] + col_j - rp)
+        rows = idx[:, 0]                             # global row per block row
+        a_self = base[rows] + col[rows]              # diagonal rule, no clamp
+        return a_off.at[:, 0].set(a_self)
 
 
 def alpha_topk(r: jnp.ndarray, c: jnp.ndarray, phi: jnp.ndarray,

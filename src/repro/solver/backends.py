@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.mrhap import run_mrhap, run_mrhap_2d
 from repro.core.streaming import streaming_hap
+from repro.runtime.trace import SPAN_BUILD, SPAN_SWEEPS, span
 from repro.solver import dense
 from repro.solver.config import SolveConfig
 from repro.solver.registry import BackendSpec, register_backend
@@ -78,10 +79,24 @@ def _topk_run(data, cfg: SolveConfig) -> RawBackendResult:
     ``EdgeList`` (already the compressed layout — dedup + pad, never
     densify). ``cfg.sweep`` routes the loop itself: single-device, or
     row-sharded over the workers mesh (``repro.solver.topk_sharded``)."""
+    with span(SPAN_BUILD):
+        s3k, idx, n = _topk_layout(data, cfg)
+    with span(SPAN_SWEEPS):
+        state, e, n_sweeps, conv, trace = _topk_sweeps(s3k, idx, n, cfg)
+        n_sweeps = int(n_sweeps)
+        trace = np.asarray(trace)[:n_sweeps]
+    converged = bool(conv) if cfg.stop == "converged" else None
+    return RawBackendResult(
+        exemplars=e, n_sweeps=n_sweeps, converged=converged, trace=trace,
+        state=state if cfg.keep_state else None)
+
+
+def _topk_layout(data, cfg: SolveConfig):
+    """-> ((L, N, kk) value stack, (N, kk) column map, N)."""
     import jax
 
     from repro.graph.edges import EdgeList
-    from repro.solver import topk, topk_sharded
+    from repro.solver import topk
 
     if isinstance(data, EdgeList):
         el = data.without_self_loops().deduplicated()
@@ -97,17 +112,24 @@ def _topk_run(data, cfg: SolveConfig) -> RawBackendResult:
         s_rows, idx = topk._with_self_slot(
             jnp.asarray(vals), jnp.asarray(idx_off), jnp.asarray(pref))
         s3k = jnp.broadcast_to(s_rows[None], (cfg.levels, *s_rows.shape))
+        return s3k, idx, n
+    arr = jnp.asarray(data)
+    n = arr.shape[1] if arr.ndim == 3 else arr.shape[0]
+    k = topk.resolve_k(cfg.k, n)
+    if arr.ndim == 3:
+        s3k, idx = topk.compress_stack(arr, k)
     else:
-        arr = jnp.asarray(data)
-        n = arr.shape[1] if arr.ndim == 3 else arr.shape[0]
-        k = topk.resolve_k(cfg.k, n)
-        if arr.ndim == 3:
-            s3k, idx = topk.compress_stack(arr, k)
-        else:
-            s3k, idx = topk.build_from_points(
-                arr, k, cfg.levels, metric=cfg.metric,
-                preference=cfg.preference,
-                key=jax.random.PRNGKey(cfg.seed), config=cfg)
+        s3k, idx = topk.build_from_points(
+            arr, k, cfg.levels, metric=cfg.metric,
+            preference=cfg.preference,
+            key=jax.random.PRNGKey(cfg.seed), config=cfg)
+    return s3k, idx, n
+
+
+def _topk_sweeps(s3k, idx, n: int, cfg: SolveConfig):
+    """The sweep loop ``cfg.sweep`` and the checkpoint settings pick;
+    -> ``(state, exemplars, n_sweeps, converged, trace)``."""
+    from repro.solver import topk, topk_sharded
 
     sweep_mode = topk_sharded.resolve_sweep(cfg.sweep, n=n,
                                             n_devices=cfg.device_count())
@@ -121,26 +143,17 @@ def _topk_run(data, cfg: SolveConfig) -> RawBackendResult:
             sweep_mode = "single"
     if cfg.checkpoint_every > 0 or cfg.resume_from:
         from repro.solver import checkpointing
-        state, e, n_sweeps, conv, trace = \
-            checkpointing.run_topk_checkpointed(
-                s3k, idx, cfg,
-                mesh=mesh if sweep_mode == "sharded" else None)
-    elif sweep_mode == "sharded":
-        state, e, n_sweeps, conv, trace = topk_sharded.run_topk_sharded(
+        return checkpointing.run_topk_checkpointed(
+            s3k, idx, cfg, mesh=mesh if sweep_mode == "sharded" else None)
+    if sweep_mode == "sharded":
+        return topk_sharded.run_topk_sharded(
             s3k, idx, mesh, max_iterations=cfg.max_iterations,
             damping=cfg.damping, kappa=cfg.kappa, s_mode=cfg.s_mode,
             stop=cfg.stop, patience=cfg.patience, exchange=cfg.exchange)
-    else:
-        state, e, n_sweeps, conv, trace = topk.run_topk(
-            s3k, idx, max_iterations=cfg.max_iterations, damping=cfg.damping,
-            kappa=cfg.kappa, s_mode=cfg.s_mode, stop=cfg.stop,
-            patience=cfg.patience)
-    n_sweeps = int(n_sweeps)
-    converged = bool(conv) if cfg.stop == "converged" else None
-    return RawBackendResult(
-        exemplars=e, n_sweeps=n_sweeps, converged=converged,
-        trace=np.asarray(trace)[:n_sweeps],
-        state=state if cfg.keep_state else None)
+    return topk.run_topk(
+        s3k, idx, max_iterations=cfg.max_iterations, damping=cfg.damping,
+        kappa=cfg.kappa, s_mode=cfg.s_mode, stop=cfg.stop,
+        patience=cfg.patience)
 
 
 register_backend(BackendSpec(

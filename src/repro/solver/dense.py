@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro.core import hap
 from repro.kernels.availability import availability_pallas
 from repro.kernels.responsibility import responsibility_pallas
+from repro.runtime.trace import SCOPE_ASSIGN
 
 DenseOrder = ("sequential", "parallel", "fused")
 
@@ -133,8 +134,9 @@ def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
         def step(carry, it):
             state, e_prev = carry
             state = sweep(state, it)
-            e = assign(state)
-            return (state, e), count_changes(e, e_prev)
+            with jax.named_scope(SCOPE_ASSIGN):
+                e = assign(state)
+                return (state, e), count_changes(e, e_prev)
 
         (state, e), trace = jax.lax.scan(
             step, (init, e0), jnp.arange(max_iterations))
@@ -156,8 +158,9 @@ def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
     def body(carry):
         state, e_prev, stable, it, trace = carry
         state = sweep(state, it)
-        e = assign(state)
-        changed = count_changes(e, e_prev)
+        with jax.named_scope(SCOPE_ASSIGN):
+            e = assign(state)
+            changed = count_changes(e, e_prev)
         stable = jnp.where(changed == 0, stable + 1, jnp.int32(0))
         trace = trace.at[it].set(changed)
         return (state, e, stable, it + 1, trace)

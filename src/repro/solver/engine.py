@@ -32,6 +32,7 @@ from repro.core.similarity import (
     pairwise_similarity, set_preferences, stack_levels,
 )
 from repro.runtime import degrade, faultinject
+from repro.runtime.trace import SPAN_FINALIZE, solve_span, span
 from repro.solver.config import SolveConfig
 from repro.solver.registry import auto_select, get_backend
 from repro.solver.result import RawBackendResult, SolveResult
@@ -301,27 +302,32 @@ def solve(data, config: Optional[SolveConfig] = None,
                 "preference array, which is what preseed='graph' "
                 "produces; use a dense or dense_topk backend")
 
+    with solve_span(backend, n):
+        raw = _dispatch(spec, backend, cfg, x, s3, el)
+        with span(SPAN_FINALIZE):
+            return _finalize(raw, n, backend)
+
+
+def _dispatch(spec, backend: str, cfg: SolveConfig, x, s3, el
+              ) -> RawBackendResult:
+    """Hand the normalized input to the backend in the form it takes."""
     if el is not None and spec.accepts_edges:
-        raw = spec.run(el, cfg)
-    elif spec.needs_points:
-        raw = spec.run(x, cfg)
-    elif spec.accepts_points and x is not None and s3 is None:
+        return spec.run(el, cfg)
+    if spec.needs_points:
+        return spec.run(x, cfg)
+    if spec.accepts_points and x is not None and s3 is None:
         # points-capable backend (dense_topk, graph_affinity): hand it the
         # raw points so its own (compressed) similarity build runs and the
         # dense N x N matrix is never materialized here
-        raw = spec.run(x, cfg)
-    else:
-        if s3 is None:
-            s3 = (_densify_edges(el, cfg) if el is not None
-                  else _build_similarity(x, cfg, backend))
-        if spec.mesh_kind:
-            mesh, multiple = _prepare_mesh(spec, cfg)
-            s3, _ = pad_similarity(s3, multiple)
-            raw = spec.run(s3, cfg.replace(mesh=mesh))
-        else:
-            raw = _run_degradable(spec, s3, cfg, backend)
-
-    return _finalize(raw, n, backend)
+        return spec.run(x, cfg)
+    if s3 is None:
+        s3 = (_densify_edges(el, cfg) if el is not None
+              else _build_similarity(x, cfg, backend))
+    if spec.mesh_kind:
+        mesh, multiple = _prepare_mesh(spec, cfg)
+        s3, _ = pad_similarity(s3, multiple)
+        return spec.run(s3, cfg.replace(mesh=mesh))
+    return _run_degradable(spec, s3, cfg, backend)
 
 
 def _run_degradable(spec, s3, cfg: SolveConfig, backend: str

@@ -19,7 +19,6 @@ which holds exemplar quality to within a couple of purity points.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -32,6 +31,7 @@ from repro.kernels.topk_ops import (
     tau_topk,
 )
 from repro.kernels.topk_similarity import topk_from_dense
+from repro.runtime.trace import SWEEP_PROGRAM
 from repro.solver import dense
 
 #: default neighbors per row (excluding self) when ``SolveConfig.k`` is
@@ -236,10 +236,6 @@ def make_topk_sweep(idx: jnp.ndarray, *, damping: float, kappa: float,
     return sweep, assign
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("max_iterations", "damping", "kappa", "s_mode",
-                     "stop", "patience"))
 def run_topk(
     s3k: jnp.ndarray,
     idx: jnp.ndarray,
@@ -266,3 +262,8 @@ def run_topk(
         init, sweep, assign, levels, n, max_iterations=max_iterations,
         stop=stop, patience=patience)
     return TopKState(state, idx), e, n_sweeps, conv, trace
+
+
+run_topk.__name__ = SWEEP_PROGRAM         # the XLA program's name, see there
+run_topk = jax.jit(run_topk, static_argnames=(
+    "max_iterations", "damping", "kappa", "s_mode", "stop", "patience"))
