@@ -17,7 +17,9 @@ and at k < N - 1 they are the sparsified AP of Xia et al. (0910.1650).
 Row reductions (rho's top-2, phi, c) are O(N * kk) dense-on-compressed
 work; the column-wise availability statistics become a scatter/segment
 sum over the incoming-edge lists (the transpose of ``idx``), the one
-genuinely sparse primitive in the sweep.
+genuinely sparse primitive in the sweep. Alpha reads them back with one
+(N, kk) gather per level, of the three per-column statistics summed
+first, plus an (N,) gather for the self slot.
 """
 from __future__ import annotations
 
@@ -83,14 +85,19 @@ def alpha_from_stats(r: jnp.ndarray, idx: jnp.ndarray, col: jnp.ndarray,
     full-length vectors and the local caller its own (N,) statistics —
     identical arithmetic either way (the self-slot gather is an identity
     gather on one device).
+
+    The three statistics are summed in (N,) space and gathered once,
+    ``(base + rdiag + col)[idx]``: the same f32 additions in the same
+    order as summing the three gathered (N, kk) blocks, one walk through
+    ``idx`` instead of three. The self slot likewise gathers
+    ``base + col`` once.
     """
     with jax.named_scope(trace.SCOPE_GATHER):
-        base_j = base[idx]
-        col_j = col[idx]
+        t = base + rdiag + col
         rp = jnp.maximum(r, 0.0)
-        a_off = jnp.minimum(0.0, base_j + rdiag[idx] + col_j - rp)
+        a_off = jnp.minimum(0.0, t[idx] - rp)
         rows = idx[:, 0]                             # global row per block row
-        a_self = base[rows] + col[rows]              # diagonal rule, no clamp
+        a_self = (base + col)[rows]                  # diagonal rule, no clamp
         return a_off.at[:, 0].set(a_self)
 
 
