@@ -36,6 +36,8 @@ SUpdateMode = Literal["off", "paper", "evidence"]
 
 
 class HAPState(NamedTuple):
+    """s/r/a are (L, N, N) on the dense path; the sparse path holds
+    (L, N, kk), or a tuple of (L, N_b, kk_b) row blocks."""
     s: jnp.ndarray    # (L, N, N) similarities (levels may diverge via eq 2.7)
     r: jnp.ndarray    # (L, N, N) responsibilities (rho)
     a: jnp.ndarray    # (L, N, N) availabilities (alpha)
@@ -139,13 +141,21 @@ def s_next_level(
 
 
 # ------------------------------------------------------------------- sweeps
-def hap_init(s3: jnp.ndarray) -> HAPState:
-    """Paper init: alpha = rho = 0, tau = +inf, phi = 0, c = 0."""
-    levels, n, _ = s3.shape
-    z3 = jnp.zeros_like(s3)
-    zv = jnp.zeros((levels, n), s3.dtype)
-    tau = jnp.full((levels, n), jnp.inf, s3.dtype)
+def hap_init(s3) -> HAPState:
+    """Paper init: alpha = rho = 0, tau = +inf, phi = 0, c = 0. ``s3``
+    is one (L, N, ...) stack or a tuple of (L, N_b, ...) row blocks."""
+    blocks = jax.tree.leaves(s3)
+    levels, dtype = blocks[0].shape[0], blocks[0].dtype
+    n = sum(b.shape[1] for b in blocks)
+    z3 = jax.tree.map(jnp.zeros_like, s3)
+    zv = jnp.zeros((levels, n), dtype)
+    tau = jnp.full((levels, n), jnp.inf, dtype)
     return HAPState(s=s3, r=z3, a=z3, tau=tau, phi=zv, c=zv)
+
+
+def _levels(x, sl: slice):
+    """Levels ``sl`` of a level-stacked array or of each row block."""
+    return jax.tree.map(lambda b: b[sl], x)
 
 
 def _damp(old: jnp.ndarray, new: jnp.ndarray, lam: float) -> jnp.ndarray:
@@ -205,7 +215,8 @@ def jacobi_sweep(
     The inter-level scaffolding — tau/c gated on ``first_iter`` (§3.0.1),
     phi from the previous iteration's alpha, the optional Eq 2.7
     similarity refinement — is schedule-defining and shared; the two
-    heavy per-entry updates vary by backend:
+    heavy per-entry updates vary by backend (s/r/a may be tuples of row
+    blocks, which the injected updates and reducers take as they are):
 
         update_r(s, a, tau, r_old) -> damped rho   (level-stacked)
         update_a(r, c, phi, a_old) -> damped alpha
@@ -223,7 +234,7 @@ def jacobi_sweep(
     # --- Job 1 ---------------------------------------------------------
     # tau^{l+1} from level l's previous-iteration rho/c; tau[0] stays +inf.
     with jax.named_scope(trace.SCOPE_TAU):
-        tau_new = red.tau(r[:-1], c[:-1])                       # (L-1, N)
+        tau_new = red.tau(_levels(r, slice(None, -1)), c[:-1])   # (L-1, N)
         tau_new = jnp.concatenate([tau[:1], tau_new], axis=0)
     with jax.named_scope(trace.SCOPE_C):
         c_new = red.c(a, r)                                     # (L, N)
@@ -238,15 +249,20 @@ def jacobi_sweep(
     # --- Job 2 ---------------------------------------------------------
     # phi^{l-1} from level l's alpha (previous iteration); phi[L-1] stays 0.
     with jax.named_scope(trace.SCOPE_PHI):
-        phi_new = red.phi(a[1:], s[1:])                         # (L-1, N)
+        phi_new = red.phi(_levels(a, slice(1, None)),
+                          _levels(s, slice(1, None)))           # (L-1, N)
         phi = jnp.concatenate([phi_new, phi[-1:]], axis=0)
     with jax.named_scope(trace.SCOPE_ALPHA):
         a = update_a(r, c, phi, a)
 
     if s_mode != "off":
         with jax.named_scope(trace.SCOPE_S_NEXT):
-            s_upd = red.s_next(s[1:], a[:-1], r[:-1], kappa, s_mode)
-            s = jnp.concatenate([s[:1], s_upd], axis=0)
+            s_upd = red.s_next(
+                _levels(s, slice(1, None)), _levels(a, slice(None, -1)),
+                _levels(r, slice(None, -1)), kappa, s_mode)
+            s = jax.tree.map(
+                lambda s0, su: jnp.concatenate([s0[:1], su], axis=0),
+                s, s_upd)
     return HAPState(s, r, a, tau, phi, c)
 
 

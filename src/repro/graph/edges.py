@@ -13,7 +13,10 @@ both ways against the rest of the system:
 * ``to_topk`` / ``to_dense`` — an edge list becomes the compressed
   top-k layout (``dense_topk`` consumes it natively) or a dense
   ``(N, N)`` similarity matrix (every dense / distributed backend
-  consumes it via the engine's densify routing).
+  consumes it via the engine's densify routing);
+* ``to_blocks`` — the same layout in row blocks of like degree, so a
+  power-law graph's hubs do not set every row's width (``dense_topk``'s
+  single-device sweep consumes it).
 
 Conventions shared with the solver:
 
@@ -50,6 +53,66 @@ def inert_fill(weight: np.ndarray) -> np.float32:
     lo = float(weight.min())
     span = float(weight.max()) - lo
     return np.float32(lo - 2.0 * span - 1.0)
+
+
+#: rows whose degree + 1 is at most this share the narrowest block of
+#: ``EdgeList.to_blocks``: the layout's slack is at most this many
+#: entries a row
+MIN_BLOCK_WIDTH = 8
+
+
+def _strictly_row_major(src: np.ndarray, dst: np.ndarray) -> bool:
+    """Whether the edges are sorted (src asc, dst asc) with no duplicate."""
+    if src.size < 2:
+        return True
+    ds = np.diff(src)                   # int32 ids: no difference overflows
+    return bool(np.all((ds > 0) | ((ds == 0) & (np.diff(dst) > 0))))
+
+
+def _width_class(width: np.ndarray) -> np.ndarray:
+    """0 for a width up to ``MIN_BLOCK_WIDTH``, then c for
+    ``MIN_BLOCK_WIDTH`` x (2^(c-1), 2^c]: the bit length of
+    (width - 1) // ``MIN_BLOCK_WIDTH``."""
+    q = (np.maximum(width, MIN_BLOCK_WIDTH) - 1) // MIN_BLOCK_WIDTH
+    return np.frexp(q.astype(np.float64))[1].astype(np.int8)
+
+
+@dataclasses.dataclass(frozen=True)
+class EdgeBlocks:
+    """An edge list laid out for the sparse sweep (``EdgeList.to_blocks``).
+
+    ``vals[b]`` and ``idx[b]`` are block b's (N_b, w_b) off-diagonal
+    similarities and laid-out column ids; its rows are the laid-out rows
+    ``offsets[b]:offsets[b + 1]``, and ``nodes[i]`` is the original id of
+    laid-out row i. ``single`` wraps a ``to_topk`` layout as one block.
+    """
+    nodes: np.ndarray
+    vals: tuple
+    idx: tuple
+    n_edges: int
+    max_degree: int
+
+    @classmethod
+    def single(cls, vals, idx) -> "EdgeBlocks":
+        n = vals.shape[0]
+        deg = np.sum(idx != np.arange(n)[:, None], axis=1)
+        return cls(np.arange(n, dtype=np.int32), (vals,), (idx,),
+                   int(deg.sum()), int(deg.max(initial=0)))
+
+    @property
+    def offsets(self) -> np.ndarray:
+        return np.concatenate([[0], np.cumsum([v.shape[0]
+                                               for v in self.vals])])
+
+    @property
+    def entries(self) -> int:
+        """Stored edges plus one self slot a row."""
+        return self.n_edges + len(self.nodes)
+
+    @property
+    def padded(self) -> int:
+        """Entries the sweep computes on, self slots included."""
+        return sum(v.shape[0] * (v.shape[1] + 1) for v in self.vals)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,16 +193,20 @@ class EdgeList:
     # ------------------------------------------------------ normalization
     def without_self_loops(self) -> "EdgeList":
         """Drop ``src == dst`` edges — the diagonal is the preference
-        slot in every solver layout, never an edge."""
+        slot in every solver layout, never an edge. A list without one
+        comes back as it is."""
         keep = self.src != self.dst
+        if keep.all():
+            return self
         return EdgeList(self.src[keep], self.dst[keep], self.weight[keep],
                         self.n_nodes)
 
     def deduplicated(self) -> "EdgeList":
         """Collapse duplicate ``(src, dst)`` pairs, keeping the maximum
         weight (the same winner a segment-max selection would pick).
-        Output is sorted (src asc, dst asc)."""
-        if self.n_edges == 0:
+        Output is sorted (src asc, dst asc); a list that already is, with
+        no duplicate, comes back as it is."""
+        if self.n_edges == 0 or _strictly_row_major(self.src, self.dst):
             return self
         # primary src, secondary dst, then weight desc: the first edge of
         # each (src, dst) run is the keeper
@@ -198,6 +265,18 @@ class EdgeList:
         vals, idx = build_topk_similarity(x, k, cfg)
         return cls.from_topk(np.asarray(vals), np.asarray(idx), x.shape[0])
 
+    def _best_per_row(self, k: int):
+        """Per row the k best edges by (weight desc, dst asc), as
+        ``(src, dst, weight)`` sorted (src asc, dst asc)."""
+        order = np.lexsort((self.dst, -self.weight, self.src))
+        s = self.src[order]
+        starts = np.concatenate(
+            [[0], np.cumsum(np.bincount(s, minlength=self.n_nodes))[:-1]])
+        keep = (np.arange(len(s)) - starts[s]) < k
+        ks, kd, kw = s[keep], self.dst[order][keep], self.weight[order][keep]
+        order2 = np.lexsort((kd, ks))
+        return ks[order2], kd[order2], kw[order2]
+
     def to_topk(self, k: Optional[int] = None, fill=None
                 ) -> tuple[np.ndarray, np.ndarray]:
         """Edges -> the compressed ``(N, k)`` off-diagonal layout.
@@ -222,22 +301,74 @@ class EdgeList:
             np.arange(n, dtype=np.int32)[:, None], (n, k)).copy()
         if self.n_edges == 0:
             return vals, idx
-        # rank edges inside each row by (weight desc, dst asc)...
-        order = np.lexsort((self.dst, -self.weight, self.src))
-        s = self.src[order]
-        starts = np.concatenate(
-            [[0], np.cumsum(np.bincount(s, minlength=n))[:-1]])
-        keep = (np.arange(len(s)) - starts[s]) < k
-        ks, kd, kw = s[keep], self.dst[order][keep], self.weight[order][keep]
-        # ...then emit the keepers column-ascending (the build layout)
-        order2 = np.lexsort((kd, ks))
-        ks, kd, kw = ks[order2], kd[order2], kw[order2]
+        # the keepers, emitted column-ascending (the build layout)
+        ks, kd, kw = self._best_per_row(k)
         starts2 = np.concatenate(
             [[0], np.cumsum(np.bincount(ks, minlength=n))[:-1]])
         pos = np.arange(len(ks)) - starts2[ks]
         vals[ks, pos] = kw
         idx[ks, pos] = kd
         return vals, idx
+
+    def to_blocks(self, k: Optional[int] = None, fill=None) -> "EdgeBlocks":
+        """Edges -> the ``to_topk`` layout in row blocks of like degree.
+
+        A row's width class is that of its degree + 1: one class for
+        every width up to ``MIN_BLOCK_WIDTH``, then one for each range
+        (w/2, w] of a power of two w. Nodes are relabelled once, in order
+        of (class, original id), so laid-out row i is node ``nodes[i]``,
+        a block's rows are one range of laid-out ids, and every (N,)
+        vector of the sweep is the concatenation of the blocks'. Each
+        block is as wide as its widest row (at least one edge slot) and
+        laid out as ``to_topk`` lays out rows: neighbours in ascending
+        order of their original ids, named by laid-out id, then padding
+        ``(fill, own row)``. A row's width with its self slot is then at
+        most ``MIN_BLOCK_WIDTH`` or below 2 x (degree + 1): the blocks
+        hold fewer than 2 x (E + N) + ``MIN_BLOCK_WIDTH`` x N entries.
+
+        With a single class no node moves, and the one block is
+        ``to_topk`` at the largest degree, bit for bit. ``k`` first keeps
+        each row's k best edges by (weight desc, dst asc), as ``to_topk``
+        does. Duplicates are not merged here.
+        """
+        n = self.n_nodes
+        if k is not None and k < 1:
+            raise ValueError(f"to_blocks needs k >= 1; got {k}")
+        if fill is None:
+            fill = inert_fill(self.weight)
+        if k is None and _strictly_row_major(self.src, self.dst):
+            src, dst, w = self.src, self.dst, self.weight
+        else:
+            src, dst, w = self._best_per_row(
+                k if k is not None else max(self.max_degree, 1))
+        first = np.searchsorted(src, np.arange(n + 1))    # rows are sorted
+        deg = np.diff(first)
+        cls = _width_class(deg + 1)
+        nodes = np.argsort(cls, kind="stable").astype(np.int32)
+        laid = np.empty(n, np.int32)
+        laid[nodes] = np.arange(n, dtype=np.int32)
+        lo = np.flatnonzero(np.diff(cls[nodes], prepend=-1))
+        rows = np.diff(lo, append=n)
+        width = np.maximum(np.maximum.reduceat(deg[nodes], lo), 1)
+        # one flat buffer, block after block, row-major inside each
+        base = np.concatenate([[0], np.cumsum(rows * width)])
+        blk = np.repeat(np.arange(len(lo)), rows)       # laid-out row -> block
+        row_w = width[blk]
+        row_at = base[blk] + (np.arange(n) - lo[blk]) * row_w
+        vals = np.full(int(base[-1]), fill, np.float32)
+        idx = np.repeat(np.arange(n, dtype=np.int32), row_w)
+        # edge e of node v (its j-th) lands at row_at[laid[v]] + j
+        at = np.repeat(row_at[laid] - first[:-1], deg) + np.arange(len(src))
+        vals[at] = w
+        idx[at] = laid[dst]
+        cut = [(int(a), int(b)) for a, b in zip(base[:-1], base[1:])]
+        return EdgeBlocks(
+            nodes=nodes,
+            vals=tuple(vals[a:b].reshape(r, wd) for (a, b), r, wd
+                       in zip(cut, rows, width)),
+            idx=tuple(idx[a:b].reshape(r, wd) for (a, b), r, wd
+                      in zip(cut, rows, width)),
+            n_edges=len(src), max_degree=int(deg.max(initial=0)))
 
     def to_dense(self, fill=None) -> np.ndarray:
         """Edges -> dense ``(N, N)`` similarity, missing entries =

@@ -5,7 +5,15 @@ Layout contract (produced by ``repro.solver.topk``): per level,
     s, r, a : (N, kk) with kk = k + 1
     idx     : (N, kk) i32, shared across levels;
               idx[i, 0] == i (the "self" slot — preference / rho_ii /
-              alpha_ii live here), idx[i, 1:] ascending neighbor columns.
+              alpha_ii live here), idx[i, 1:] the neighbor columns
+              (ascending from the point build), padding pointing at i.
+
+An edge list of uneven degrees comes as row blocks instead
+(``repro.graph.edges.EdgeList.to_blocks``): a sequence of such (N_b,
+kk_b) pairs whose rows are consecutive ranges of 0..N-1. The row-wise
+ops run on each block as they are; the column statistics take blocks
+(``col_stats_topk``, ``tau_topk``, ``alpha_topk``): one (N,) column sum
+accumulated over the blocks' scatters, read back by one gather a block.
 
 Semantics: a missing edge is a similarity of -inf. Under that convention
 every dense update (Eqs 2.1-2.6) restricted to the stored positions is
@@ -47,32 +55,51 @@ def rho_topk(s: jnp.ndarray, a: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
     return s + jnp.minimum(tau[:, None], -row_max_excl)
 
 
-def col_partial_topk(r: jnp.ndarray, idx: jnp.ndarray,
-                     n_total: int) -> jnp.ndarray:
+def as_blocks(x) -> tuple:
+    """A layout given as one array or as a sequence of row blocks ->
+    the tuple of its blocks."""
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
+
+
+def join_rows(parts) -> jnp.ndarray:
+    """Per-block (..., N_b) vectors -> the (..., N) vector."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
+
+
+def col_partial_topk(r: jnp.ndarray, idx: jnp.ndarray, n_total: int,
+                     col: jnp.ndarray | None = None) -> jnp.ndarray:
     """A row block's contributions to the (n_total,) availability column
     sum: scatter of max(0, rho) over the block's stored edges, self slot
-    excluded. On one device (``n_total == N``, all rows) this IS the full
-    column statistic; a row-sharded sweep psums the per-shard partials
-    (or all-gathers rho and scatters the full edge set at once — the
-    allgather exchange, same accumulation order as this single scatter).
+    excluded, added into ``col`` (zeros when None). On one device
+    (``n_total == N``, all rows) this IS the full column statistic; a
+    row-sharded sweep psums the per-shard partials (or all-gathers rho
+    and scatters the full edge set at once — the allgather exchange, same
+    accumulation order as this single scatter).
     """
     with jax.named_scope(trace.SCOPE_COLSUM):
         rp = jnp.maximum(r, 0.0).at[:, 0].set(0.0)  # self slot excluded
-        return jnp.zeros((n_total,), r.dtype).at[idx.ravel()].add(
-            rp.ravel())
+        if col is None:
+            col = jnp.zeros((n_total,), r.dtype)
+        return col.at[idx.ravel()].add(rp.ravel())
 
 
-def col_stats_topk(r: jnp.ndarray, idx: jnp.ndarray
-                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+def col_stats_topk(r, idx) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Column statistics over incoming edges (the scatter/segment sum).
 
     Returns ``col`` (N,) = sum over stored edges (i -> j), i != j, of
     max(0, rho_ij), indexed by target j, and ``rdiag`` (N,) = rho_jj
     (the self slot). ``col`` is the availability/tau column sum; only
     rows that actually keep an edge to j contribute — exactly the dense
-    sum when absent responsibilities are -inf (clamped to 0).
+    sum when absent responsibilities are -inf (clamped to 0). ``r`` and
+    ``idx`` are one (N, kk) layout or matching sequences of row blocks,
+    whose scatters accumulate into the one ``col``.
     """
-    return col_partial_topk(r, idx, r.shape[0]), r[:, 0]
+    r, idx = as_blocks(r), as_blocks(idx)
+    n = sum(i.shape[0] for i in idx)
+    col = None
+    for r_b, i_b in zip(r, idx):
+        col = col_partial_topk(r_b, i_b, n, col)
+    return col, join_rows([r_b[:, 0] for r_b in r])
 
 
 def alpha_from_stats(r: jnp.ndarray, idx: jnp.ndarray, col: jnp.ndarray,
@@ -101,11 +128,13 @@ def alpha_from_stats(r: jnp.ndarray, idx: jnp.ndarray, col: jnp.ndarray,
         return a_off.at[:, 0].set(a_self)
 
 
-def alpha_topk(r: jnp.ndarray, c: jnp.ndarray, phi: jnp.ndarray,
-               idx: jnp.ndarray) -> jnp.ndarray:
-    """Eq 2.2/2.3 on stored entries via gathered column statistics."""
+def alpha_topk(r, c: jnp.ndarray, phi: jnp.ndarray, idx):
+    """Eq 2.2/2.3 on stored entries via gathered column statistics; one
+    layout, or row blocks (then a tuple of blocks)."""
     col, rdiag = col_stats_topk(r, idx)
-    return alpha_from_stats(r, idx, col, c + phi, rdiag)
+    out = tuple(alpha_from_stats(r_b, i_b, col, c + phi, rdiag)
+                for r_b, i_b in zip(as_blocks(r), as_blocks(idx)))
+    return out if isinstance(r, (tuple, list)) else out[0]
 
 
 def tau_from_stats(c: jnp.ndarray, rdiag: jnp.ndarray,
@@ -116,15 +145,17 @@ def tau_from_stats(c: jnp.ndarray, rdiag: jnp.ndarray,
     return c + rdiag + col
 
 
-def tau_topk(r: jnp.ndarray, c: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+def tau_topk(r, c: jnp.ndarray, idx) -> jnp.ndarray:
     """Eq 2.4: tau_j^{l+1} = c_j + rho_jj + sum_{k!=j} max(0, rho_kj).
 
     The column sum is read back through the self slot's row ids, as a
     row-sharded caller reads its block: added straight to the scatter's
     result, ``c + rho_jj`` would be folded by XLA into the scatter's
-    initial value, which sums the same terms in another order."""
+    initial value, which sums the same terms in another order. One
+    layout or row blocks."""
     col, rdiag = col_stats_topk(r, idx)
-    return tau_from_stats(c, rdiag, col[idx[:, 0]])
+    rows = join_rows([i_b[:, 0] for i_b in as_blocks(idx)])
+    return tau_from_stats(c, rdiag, col[rows])
 
 
 def phi_topk(a: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:

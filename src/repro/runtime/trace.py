@@ -31,6 +31,12 @@ Spans (``jax.profiler.TraceAnnotation``) are host intervals on the
 profiler's clock, so they line up with the device ops of the same
 trace. They record only while a profiler runs (``jax.profiler.trace``);
 otherwise each costs a few microseconds.
+
+Counters: ``edge_layout()`` returns what the process's last edge-list
+layout (``repro.solver.backends``, in the ``repro.solve.layout`` span)
+recorded: ``edge_layout.buckets`` (row blocks), ``.entries`` (stored
+edges plus one self slot a row), ``.padded`` (entries the sweep computes
+on), ``.max_degree`` and ``.host_s`` (seconds of the host step).
 """
 from __future__ import annotations
 
@@ -68,9 +74,19 @@ SPAN_SWEEPS = "repro.solve.sweeps"
 #: padding strip, canonical exemplars, labels
 SPAN_FINALIZE = "repro.solve.finalize"
 
+#: the spans every ``dense_topk`` solve writes
 SPANS = (SPAN_SOLVE, SPAN_BUILD, SPAN_SWEEPS, SPAN_FINALIZE)
 
+#: an edge list laid out for the sweep, on the host (inside the build;
+#: edge-list input only)
+SPAN_LAYOUT = "repro.solve.layout"
+
+#: the counters of one edge-list layout, in this order
+EDGE_LAYOUT_COUNTERS = ("buckets", "entries", "padded", "max_degree",
+                        "host_s")
+
 _solve_calls = itertools.count(1)
+_edge_layout: dict | None = None
 
 
 def span(name: str, **meta) -> jax.profiler.TraceAnnotation:
@@ -82,3 +98,16 @@ def solve_span(backend: str, n: int) -> jax.profiler.TraceAnnotation:
     """The ``repro.solve`` span of one call, numbered within the
     process so the solves of one trace are told apart."""
     return span(SPAN_SOLVE, backend=backend, n=n, call=next(_solve_calls))
+
+
+def record_edge_layout(**counters) -> None:
+    """Keep one edge-list layout's counters (``EDGE_LAYOUT_COUNTERS``)."""
+    global _edge_layout
+    _edge_layout = {f"edge_layout.{name}": counters[name]
+                    for name in EDGE_LAYOUT_COUNTERS}
+
+
+def edge_layout() -> dict | None:
+    """The counters of the process's last edge-list layout, by their
+    ``edge_layout.*`` names; None before the first."""
+    return None if _edge_layout is None else dict(_edge_layout)

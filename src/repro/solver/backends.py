@@ -27,12 +27,16 @@ graph_affinity     Borůvka min-edge/contract affinity clustering over
 """
 from __future__ import annotations
 
+import time
+
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mrhap import run_mrhap, run_mrhap_2d
 from repro.core.streaming import streaming_hap
-from repro.runtime.trace import SPAN_BUILD, SPAN_SWEEPS, span
+from repro.runtime.trace import (
+    SPAN_BUILD, SPAN_LAYOUT, SPAN_SWEEPS, record_edge_layout, span,
+)
 from repro.solver import dense
 from repro.solver.config import SolveConfig
 from repro.solver.registry import BackendSpec, register_backend
@@ -78,41 +82,47 @@ def _topk_run(data, cfg: SolveConfig) -> RawBackendResult:
     never materialized), a similarity stack (row-wise compression), or an
     ``EdgeList`` (already the compressed layout — dedup + pad, never
     densify). ``cfg.sweep`` routes the loop itself: single-device, or
-    row-sharded over the workers mesh (``repro.solver.topk_sharded``)."""
+    row-sharded over the workers mesh (``repro.solver.topk_sharded``).
+    The single-device loop takes an edge list in row blocks of like
+    degree (``EdgeList.to_blocks``); its exemplars come back in the
+    caller's node ids."""
+    mode, mesh = _sweep_route(data, cfg)
+    blocked = mode == "single" and not _checkpointed(cfg)
     with span(SPAN_BUILD):
-        s3k, idx, n = _topk_layout(data, cfg)
+        s3k, idx, n, nodes = _topk_layout(data, cfg, blocked)
     with span(SPAN_SWEEPS):
-        state, e, n_sweeps, conv, trace = _topk_sweeps(s3k, idx, n, cfg)
+        state, e, n_sweeps, conv, trace = _topk_sweeps(s3k, idx, cfg,
+                                                       mode, mesh)
         n_sweeps = int(n_sweeps)
         trace = np.asarray(trace)[:n_sweeps]
+    if nodes is not None:
+        e_laid = np.asarray(e)
+        e = np.empty_like(e_laid)
+        e[:, nodes] = nodes[e_laid]
+        state = state._replace(nodes=nodes)
     converged = bool(conv) if cfg.stop == "converged" else None
     return RawBackendResult(
         exemplars=e, n_sweeps=n_sweeps, converged=converged, trace=trace,
         state=state if cfg.keep_state else None)
 
 
-def _topk_layout(data, cfg: SolveConfig):
-    """-> ((L, N, kk) value stack, (N, kk) column map, N)."""
+def _checkpointed(cfg: SolveConfig) -> bool:
+    return cfg.checkpoint_every > 0 or bool(cfg.resume_from)
+
+
+def _topk_layout(data, cfg: SolveConfig, blocked: bool):
+    """-> (value stack, column map, N, nodes): an (L, N, kk) stack and
+    its (N, kk) map, or, for an edge list ``blocked`` into more than one
+    block, tuples of (L, N_b, kk_b) stacks and (N_b, kk_b) maps with
+    ``nodes`` the original id of each laid-out row (else None)."""
     import jax
 
     from repro.graph.edges import EdgeList
     from repro.solver import topk
 
     if isinstance(data, EdgeList):
-        el = data.without_self_loops().deduplicated()
-        n = el.n_nodes
-        # an edge list brings its own sparsity: keep every stored edge
-        # unless cfg.k asks for a tighter (weight desc, dst asc) cut
-        k = (topk.resolve_k(cfg.k, n) if cfg.k is not None
-             else max(1, min(el.max_degree, n - 1)))
-        vals, idx_off = el.to_topk(k)
-        pref = el.edge_preferences(
-            cfg.preference if cfg.preference is not None else "median",
-            seed=cfg.seed)
-        s_rows, idx = topk._with_self_slot(
-            jnp.asarray(vals), jnp.asarray(idx_off), jnp.asarray(pref))
-        s3k = jnp.broadcast_to(s_rows[None], (cfg.levels, *s_rows.shape))
-        return s3k, idx, n
+        with span(SPAN_LAYOUT):
+            return _edge_layout(data, cfg, blocked)
     arr = jnp.asarray(data)
     n = arr.shape[1] if arr.ndim == 3 else arr.shape[0]
     k = topk.resolve_k(cfg.k, n)
@@ -123,29 +133,82 @@ def _topk_layout(data, cfg: SolveConfig):
             arr, k, cfg.levels, metric=cfg.metric,
             preference=cfg.preference,
             key=jax.random.PRNGKey(cfg.seed), config=cfg)
-    return s3k, idx, n
+    return s3k, idx, n, None
 
 
-def _topk_sweeps(s3k, idx, n: int, cfg: SolveConfig):
-    """The sweep loop ``cfg.sweep`` and the checkpoint settings pick;
+def _edge_layout(el, cfg: SolveConfig, blocked: bool):
+    """The edge list's layout on the host, then on the device; records
+    the ``edge_layout.*`` counters (``repro.runtime.trace``)."""
+    from repro.graph.edges import EdgeBlocks
+    from repro.solver import topk
+
+    t0 = time.perf_counter()
+    el = el.without_self_loops().deduplicated()
+    n = el.n_nodes
+    # an edge list brings its own sparsity: keep every stored edge
+    # unless cfg.k asks for a tighter (weight desc, dst asc) cut
+    if blocked:
+        lay = el.to_blocks(cfg.k)
+    else:
+        # the sharded and checkpointed sweeps take one block, as wide as
+        # the widest row
+        k = (topk.resolve_k(cfg.k, n) if cfg.k is not None
+             else max(1, min(el.max_degree, n - 1)))
+        lay = EdgeBlocks.single(*el.to_topk(k))
+    pref = el.edge_preferences(
+        cfg.preference if cfg.preference is not None else "median",
+        seed=cfg.seed)[lay.nodes]
+    s3k, idx = [], []
+    for v, i, lo, hi in zip(lay.vals, lay.idx, lay.offsets,
+                            lay.offsets[1:]):
+        s_rows, i_full = topk._with_self_slot(
+            jnp.asarray(v), jnp.asarray(i), jnp.asarray(pref[lo:hi]),
+            start=int(lo))
+        s3k.append(jnp.broadcast_to(s_rows[None],
+                                    (cfg.levels, *s_rows.shape)))
+        idx.append(i_full)
+    record_edge_layout(buckets=len(lay.vals), entries=lay.entries,
+                       padded=lay.padded, max_degree=lay.max_degree,
+                       host_s=time.perf_counter() - t0)
+    if len(s3k) == 1:
+        return s3k[0], idx[0], n, None
+    return tuple(s3k), tuple(idx), n, lay.nodes
+
+
+def _sweep_route(data, cfg: SolveConfig):
+    """-> (``"single"`` | ``"sharded"``, the workers mesh or None): the
+    sweep loop ``cfg.sweep`` picks for this input."""
+    from repro.graph.edges import EdgeList
+    from repro.solver import topk_sharded
+
+    if isinstance(data, EdgeList):
+        n = data.n_nodes
+    else:
+        shape = np.shape(data)
+        n = shape[1] if len(shape) == 3 else shape[0]
+    mode = topk_sharded.resolve_sweep(cfg.sweep, n=n,
+                                      n_devices=cfg.device_count())
+    if mode != "sharded":
+        return mode, None
+    from repro.solver.engine import _prepare_mesh
+    mesh, _ = _prepare_mesh("1d", cfg)
+    if mesh.shape["workers"] == 1:
+        # a 1-worker shard_map pays collective/dispatch overhead to
+        # shard nothing (the build had the same regression) — the
+        # single-device loop is the same arithmetic, minus the detour
+        return "single", None
+    return mode, mesh
+
+
+def _topk_sweeps(s3k, idx, cfg: SolveConfig, mode: str, mesh):
+    """The sweep loop of ``_sweep_route`` and the checkpoint settings;
     -> ``(state, exemplars, n_sweeps, converged, trace)``."""
     from repro.solver import topk, topk_sharded
 
-    sweep_mode = topk_sharded.resolve_sweep(cfg.sweep, n=n,
-                                            n_devices=cfg.device_count())
-    if sweep_mode == "sharded":
-        from repro.solver.engine import _prepare_mesh
-        mesh, _ = _prepare_mesh("1d", cfg)
-        if mesh.shape["workers"] == 1:
-            # a 1-worker shard_map pays collective/dispatch overhead to
-            # shard nothing (the build had the same regression) — the
-            # single-device loop is the same arithmetic, minus the detour
-            sweep_mode = "single"
-    if cfg.checkpoint_every > 0 or cfg.resume_from:
+    if _checkpointed(cfg):
         from repro.solver import checkpointing
-        return checkpointing.run_topk_checkpointed(
-            s3k, idx, cfg, mesh=mesh if sweep_mode == "sharded" else None)
-    if sweep_mode == "sharded":
+        return checkpointing.run_topk_checkpointed(s3k, idx, cfg, mesh=mesh)
+    if mode == "sharded":
         return topk_sharded.run_topk_sharded(
             s3k, idx, mesh, max_iterations=cfg.max_iterations,
             damping=cfg.damping, kappa=cfg.kappa, s_mode=cfg.s_mode,

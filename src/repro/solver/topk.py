@@ -27,8 +27,8 @@ import jax.numpy as jnp
 from repro.core import hap
 from repro.core.preferences import random_preference
 from repro.kernels.topk_ops import (
-    alpha_topk, assignments_topk, c_topk, phi_topk, rho_topk, s_next_topk,
-    tau_topk,
+    alpha_topk, as_blocks, assignments_topk, c_topk, join_rows, phi_topk,
+    rho_topk, s_next_topk, tau_topk,
 )
 from repro.kernels.topk_similarity import topk_from_dense
 from repro.runtime.trace import SWEEP_PROGRAM
@@ -43,9 +43,14 @@ DEFAULT_K = 64
 class TopKState(NamedTuple):
     """Final message state of a ``dense_topk`` run (``keep_state``):
     ``hap`` carries (L, N, kk) s/r/a and (L, N) tau/phi/c; ``idx`` maps
-    stored positions back to global column indices."""
+    stored positions back to global column indices. An edge list laid
+    out in row blocks (``repro.graph.edges.EdgeList.to_blocks``) keeps
+    s/r/a and ``idx`` as tuples of blocks, in laid-out ids, and
+    ``nodes[i]``, the original id of laid-out row i (None: rows are not
+    relabelled)."""
     hap: hap.HAPState
     idx: jnp.ndarray
+    nodes: Optional[object] = None
 
 
 def resolve_k(k: Optional[int], n: int) -> int:
@@ -126,12 +131,15 @@ def topk_preferences(vals: jnp.ndarray, strategy, *, key=None) -> jnp.ndarray:
     raise ValueError(f"unknown preference strategy: {strategy}")
 
 
-def _with_self_slot(vals, idx, pref):
+def _with_self_slot(vals, idx, pref, start: int = 0):
+    """Prepend the self slot (preference, own row id) to rows ``start``
+    onwards of the off-diagonal layout."""
     n = vals.shape[0]
     s_rows = jnp.concatenate([pref[:, None].astype(jnp.float32), vals],
                              axis=1)
     idx_full = jnp.concatenate(
-        [jnp.arange(n, dtype=jnp.int32)[:, None], idx], axis=1)
+        [jnp.arange(start, start + n, dtype=jnp.int32)[:, None], idx],
+        axis=1)
     return s_rows, idx_full
 
 
@@ -190,39 +198,70 @@ def compress_stack(s3: jnp.ndarray, k: int
     return s3k, idx_full
 
 
-def make_topk_sweep(idx: jnp.ndarray, *, damping: float, kappa: float,
-                    s_mode: str):
+def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
     """Build the ``(sweep, assign)`` pair for the compressed layout.
 
     One definition shared by ``run_topk`` and the checkpointed segment
     runner (``repro.solver.checkpointing``) — both must execute the
     identical op sequence per sweep for resume to be bit-exact.
 
+    ``idx`` is one (N, kk) column map, or a tuple of (N_b, kk_b) row
+    blocks (then s/r/a are tuples of (L, N_b, kk_b) blocks): the row-wise
+    updates run on each block, the column statistics accumulate one (N,)
+    sum over them, and (N,) vectors are the blocks' joined in row order.
+    One block is the one-map program, op for op.
+
     The two updates that scatter and gather through ``idx`` (tau, alpha)
     run level by level (L is small and static: unrolled) rather than
     under ``vmap``: a vmapped scatter puts the level axis minor, (N*kk,
     L), which a TPU pads from L to 128 lanes (40x the state at N=2^18)."""
+    blocks = as_blocks(idx)
+    bounds = [0]
+    for i_b in blocks:
+        bounds.append(bounds[-1] + i_b.shape[0])
+    n = bounds[-1]
+
+    def per_block(fn, *xs):
+        """``fn`` on each block of the row-blocked arguments -> a tuple."""
+        return tuple(fn(*b) for b in zip(*map(as_blocks, xs)))
+
+    def like(template, parts):
+        return parts if isinstance(template, (tuple, list)) else parts[0]
+
+    def split_rows(v):                       # (L, N) -> per block (L, N_b)
+        if len(blocks) == 1:
+            return (v,)
+        return tuple(v[:, lo:hi] for lo, hi in zip(bounds, bounds[1:]))
+
+    def level(x, l):
+        return like(x, tuple(b[l] for b in as_blocks(x)))
+
     def tau_red(r, c):                       # (L-1, N, kk), (L-1, N)
-        if r.shape[0] == 0:                  # L=1: no level above
+        if c.shape[0] == 0:                  # L=1: no level above
             return jnp.zeros(c.shape, c.dtype)
-        return jnp.stack([tau_topk(r[l], c[l], idx)
-                          for l in range(r.shape[0])])
+        return jnp.stack([tau_topk(level(r, l), c[l], idx)
+                          for l in range(c.shape[0])])
 
     reducers = hap.SweepReducers(
         tau=tau_red,
-        phi=jax.vmap(phi_topk),
-        c=jax.vmap(c_topk),
-        s_next=lambda s_up, a, r, kap, mode: jax.vmap(
-            lambda su, al, rl: s_next_topk(su, al, rl, kap, mode)
-        )(s_up, a, r))
+        phi=lambda a, s: join_rows(per_block(jax.vmap(phi_topk), a, s)),
+        c=lambda a, r: join_rows(per_block(jax.vmap(c_topk), a, r)),
+        s_next=lambda s_up, a, r, kap, mode: like(s_up, per_block(
+            jax.vmap(lambda su, al, rl: s_next_topk(su, al, rl, kap, mode)),
+            s_up, a, r)))
 
     def update_r(s, a, tau, r):
-        return hap._damp(r, jax.vmap(rho_topk)(s, a, tau), damping)
+        return like(r, per_block(
+            lambda s_b, a_b, t_b, r_b: hap._damp(
+                r_b, jax.vmap(rho_topk)(s_b, a_b, t_b), damping),
+            s, a, split_rows(tau), r))
 
     def update_a(r, c, phi, a):
-        return hap._damp(a, jnp.stack([
-            alpha_topk(r[l], c[l], phi[l], idx)
-            for l in range(r.shape[0])]), damping)
+        new = [as_blocks(alpha_topk(level(r, l), c[l], phi[l], idx))
+               for l in range(c.shape[0])]
+        return like(a, tuple(
+            hap._damp(a_b, jnp.stack([lv[b] for lv in new]), damping)
+            for b, a_b in enumerate(as_blocks(a))))
 
     def sweep(state, it):
         return hap.jacobi_sweep(
@@ -230,8 +269,11 @@ def make_topk_sweep(idx: jnp.ndarray, *, damping: float, kappa: float,
             update_r=update_r, update_a=update_a, reducers=reducers)
 
     def assign(state):
-        return jax.vmap(lambda al, rl: assignments_topk(al, rl, idx))(
-            state.a, state.r)
+        return join_rows(per_block(
+            lambda a_b, r_b, i_b: jax.vmap(
+                lambda al, rl: assignments_topk(al, rl, i_b, n_total=n))(
+                    a_b, r_b),
+            state.a, state.r, idx))
 
     return sweep, assign
 
@@ -247,14 +289,16 @@ def run_topk(
     stop: str = "fixed",
     patience: int = 5,
 ):
-    """Run the sparse Jacobi schedule on a compressed (L, N, kk) stack.
+    """Run the sparse Jacobi schedule on a compressed (L, N, kk) stack
+    and its (N, kk) column map, or on matching tuples of row blocks
+    (``make_topk_sweep``).
 
     Same return contract as ``run_dense``:
     ``(state, exemplars, n_sweeps, converged, trace)``.
     """
-    s3k = s3k.astype(jnp.float32)
-    levels, n, _ = s3k.shape
+    s3k = jax.tree.map(lambda b: b.astype(jnp.float32), s3k)
     init = hap.hap_init(s3k)
+    levels, n = init.c.shape
     sweep, assign = make_topk_sweep(idx, damping=damping, kappa=kappa,
                                     s_mode=s_mode)
 
