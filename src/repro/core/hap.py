@@ -23,7 +23,7 @@ Both damp rho/alpha by ``lambda`` per level (paper §2).
 from __future__ import annotations
 
 import functools
-from typing import Literal, NamedTuple
+from typing import Literal, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,13 +37,16 @@ SUpdateMode = Literal["off", "paper", "evidence"]
 
 class HAPState(NamedTuple):
     """s/r/a are (L, N, N) on the dense path; the sparse path holds
-    (L, N, kk), or a tuple of (L, N_b, kk_b) row blocks."""
+    (L, N, kk), or a tuple of (L, N_b, kk_b) row blocks. ``col`` is the
+    sparse loop's carried column sums (``jacobi_sweep``): None on the
+    dense paths and at L = 1."""
     s: jnp.ndarray    # (L, N, N) similarities (levels may diverge via eq 2.7)
     r: jnp.ndarray    # (L, N, N) responsibilities (rho)
     a: jnp.ndarray    # (L, N, N) availabilities (alpha)
     tau: jnp.ndarray  # (L, N) upward messages; tau[0] == +inf
     phi: jnp.ndarray  # (L, N) downward messages; phi[L-1] == 0
     c: jnp.ndarray    # (L, N) cluster preferences
+    col: Optional[jnp.ndarray] = None  # (L-1, N) sum_{k!=j} max(0, rho_kj)
 
 
 class HAPResult(NamedTuple):
@@ -189,7 +192,7 @@ class SweepReducers(NamedTuple):
     dense (L, N, N) set below; the sparse top-k path injects the
     ``repro.kernels.topk_ops`` equivalents (closing over its index
     layout) so both share one schedule-defining sweep body."""
-    tau: object      # (r[:-1], c[:-1]) -> (L-1, N)   Eq 2.4
+    tau: object      # (r[:-1], c[:-1][, col]) -> (L-1, N)   Eq 2.4
     phi: object      # (a[1:], s[1:])   -> (L-1, N)   Eq 2.5
     c: object        # (a, r)           -> (L, N)     Eq 2.6
     s_next: object   # (s[1:], a[:-1], r[:-1], kappa, mode) -> (L-1, ...)
@@ -226,16 +229,26 @@ def jacobi_sweep(
     ``dense_topk`` backend injects compressed-layout updates plus its
     ``reducers``. One body keeps them numerically comparable by
     construction — the dense reductions are the default.
+
+    A state that carries ``col`` (the sparse loop at L >= 2) holds the
+    column sums sum_{k!=j} max(0, rho_kj) of levels 0..L-2 that the
+    previous sweep's alpha update scattered from the rho this sweep's
+    tau reads. ``reducers.tau`` then takes them as a third argument in
+    place of scattering that rho again, and ``update_a`` returns
+    ``(alpha, col)``, the sums it scattered, for the next sweep: a loop
+    shares no value across its iterations otherwise. The first sweep's
+    sums are zeros, the scatter of rho = 0, and its tau is discarded.
     """
     red = reducers if reducers is not None else _dense_reducers()
     s, r, a = state.s, state.r, state.a
-    tau, phi, c = state.tau, state.phi, state.c
+    tau, phi, c, col = state.tau, state.phi, state.c, state.col
 
     # --- Job 1 ---------------------------------------------------------
     # tau^{l+1} from level l's previous-iteration rho/c; tau[0] stays +inf.
     with jax.named_scope(trace.SCOPE_TAU):
-        tau_new = red.tau(_levels(r, slice(None, -1)), c[:-1])   # (L-1, N)
-        tau_new = jnp.concatenate([tau[:1], tau_new], axis=0)
+        lower = (_levels(r, slice(None, -1)), c[:-1])
+        tau_new = red.tau(*lower) if col is None else red.tau(*lower, col)
+        tau_new = jnp.concatenate([tau[:1], tau_new], axis=0)   # (L, N)
     with jax.named_scope(trace.SCOPE_C):
         c_new = red.c(a, r)                                     # (L, N)
     keep = jnp.asarray(first_iter)
@@ -253,7 +266,10 @@ def jacobi_sweep(
                           _levels(s, slice(1, None)))           # (L-1, N)
         phi = jnp.concatenate([phi_new, phi[-1:]], axis=0)
     with jax.named_scope(trace.SCOPE_ALPHA):
-        a = update_a(r, c, phi, a)
+        if col is None:
+            a = update_a(r, c, phi, a)
+        else:
+            a, col = update_a(r, c, phi, a)
 
     if s_mode != "off":
         with jax.named_scope(trace.SCOPE_S_NEXT):
@@ -263,7 +279,7 @@ def jacobi_sweep(
             s = jax.tree.map(
                 lambda s0, su: jnp.concatenate([s0[:1], su], axis=0),
                 s, s_upd)
-    return HAPState(s, r, a, tau, phi, c)
+    return HAPState(s, r, a, tau, phi, c, col)
 
 
 def hap_sweep_parallel(

@@ -12,7 +12,7 @@ An edge list of uneven degrees comes as row blocks instead
 (``repro.graph.edges.EdgeList.to_blocks``): a sequence of such (N_b,
 kk_b) pairs whose rows are consecutive ranges of 0..N-1. The row-wise
 ops run on each block as they are; the column statistics take blocks
-(``col_stats_topk``, ``tau_topk``, ``alpha_topk``): one (N,) column sum
+(``col_stats_topk``, ``alpha_topk``, ``tau_topk``): one (N,) column sum
 accumulated over the blocks' scatters, read back by one gather a block.
 
 Semantics: a missing edge is a similarity of -inf. Under that convention
@@ -27,7 +27,9 @@ work; the column-wise availability statistics become a scatter/segment
 sum over the incoming-edge lists (the transpose of ``idx``), the one
 genuinely sparse primitive in the sweep. Alpha reads them back with one
 (N, kk) gather per level, of the three per-column statistics summed
-first, plus an (N,) gather for the self slot.
+first, plus an (N,) gather for the self slot. Tau needs the same sums of
+the same rho one sweep later, so it takes the ones alpha scattered
+(``tau_topk``'s ``col``): one scatter a level a sweep.
 """
 from __future__ import annotations
 
@@ -130,11 +132,13 @@ def alpha_from_stats(r: jnp.ndarray, idx: jnp.ndarray, col: jnp.ndarray,
 
 def alpha_topk(r, c: jnp.ndarray, phi: jnp.ndarray, idx):
     """Eq 2.2/2.3 on stored entries via gathered column statistics; one
-    layout, or row blocks (then a tuple of blocks)."""
+    layout, or row blocks (then a tuple of blocks). Returns ``(alpha,
+    col)``: ``col`` is the (N,) column sum it scattered, which the
+    sparse loop carries to the next sweep's ``tau_topk``."""
     col, rdiag = col_stats_topk(r, idx)
     out = tuple(alpha_from_stats(r_b, i_b, col, c + phi, rdiag)
                 for r_b, i_b in zip(as_blocks(r), as_blocks(idx)))
-    return out if isinstance(r, (tuple, list)) else out[0]
+    return (out if isinstance(r, (tuple, list)) else out[0]), col
 
 
 def tau_from_stats(c: jnp.ndarray, rdiag: jnp.ndarray,
@@ -145,15 +149,18 @@ def tau_from_stats(c: jnp.ndarray, rdiag: jnp.ndarray,
     return c + rdiag + col
 
 
-def tau_topk(r, c: jnp.ndarray, idx) -> jnp.ndarray:
-    """Eq 2.4: tau_j^{l+1} = c_j + rho_jj + sum_{k!=j} max(0, rho_kj).
+def tau_topk(r, c: jnp.ndarray, col: jnp.ndarray, idx) -> jnp.ndarray:
+    """Eq 2.4: tau_j^{l+1} = c_j + rho_jj + sum_{k!=j} max(0, rho_kj),
+    given that column sum ``col`` of this rho (``col_stats_topk``): the
+    sparse loop carries the one its alpha update scattered at the
+    previous sweep, so tau scatters nothing.
 
     The column sum is read back through the self slot's row ids, as a
-    row-sharded caller reads its block: added straight to the scatter's
+    row-sharded caller reads its block: added straight to a scatter's
     result, ``c + rho_jj`` would be folded by XLA into the scatter's
     initial value, which sums the same terms in another order. One
     layout or row blocks."""
-    col, rdiag = col_stats_topk(r, idx)
+    rdiag = join_rows([r_b[:, 0] for r_b in as_blocks(r)])
     rows = join_rows([i_b[:, 0] for i_b in as_blocks(idx)])
     return tau_from_stats(c, rdiag, col[rows])
 

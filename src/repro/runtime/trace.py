@@ -11,7 +11,8 @@ statistics (``repro.kernels.topk_ops``) scope their scatter and their
 gathers inside the job that calls them:
 
 =================  ====================================================
-``hap_tau``        Eq 2.4, the upward message (holds ``hap_colsum``)
+``hap_tau``        Eq 2.4, the upward message (reads the column sums
+                   the previous sweep's ``hap_alpha`` scattered)
 ``hap_c``          Eq 2.6, the cluster preference (row max)
 ``hap_rho``        Eq 2.1, responsibilities (row top-2) and damping
 ``hap_phi``        Eq 2.5, the downward message (row max)

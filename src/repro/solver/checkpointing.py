@@ -8,11 +8,12 @@ module closes that gap for the two long-running backends
 * **dense_topk** (single-device and ``sweep="sharded"``) — the Jacobi
   loop runs as *segments* of the same ``lax.while_loop``
   (``dense.drive_sweeps(segmented=True)``); between segments the host
-  snapshots the compressed message state + sweep index through
-  ``repro.checkpoint``. The segment bound ``until`` is a *dynamic*
-  operand, so a whole solve compiles exactly two programs (fresh
-  first segment, resumed segments) no matter how many boundaries it
-  crosses. Because checkpointed runs always execute the segmented
+  snapshots the compressed message state, the column sums the loop
+  carries from alpha to the next sweep's tau (``col``) and the sweep
+  index through ``repro.checkpoint``. The segment bound ``until`` is a
+  *dynamic* operand, so a whole solve compiles exactly two programs
+  (fresh first segment, resumed segments) no matter how many boundaries
+  it crosses. Because checkpointed runs always execute the segmented
   program, an interrupted-and-resumed run and an uninterrupted
   checkpointed run are the *same op sequence with the same inputs* —
   resume is bit-exact by construction, and the tests additionally
@@ -34,8 +35,10 @@ module closes that gap for the two long-running backends
 
 Every checkpoint directory carries a ``solve_meta.json`` sidecar with
 the run's config/shape key; ``resume_from`` refuses a mismatched run
-rather than silently diverging. Crash points are exercised
-deterministically via ``repro.runtime.faultinject`` (sites
+rather than silently diverging. Its ``carry`` entry names the loop
+state saved beyond the messages, so a ``dense_topk`` checkpoint written
+before the carried ``col`` existed is refused too. Crash points are
+exercised deterministically via ``repro.runtime.faultinject`` (sites
 ``solver.sweep`` / ``solver.coarsen``), fired *after* each save so an
 injected crash always leaves a resumable directory.
 """
@@ -112,6 +115,7 @@ def _topk_meta(kind: str, n: int, kk: int, cfg: SolveConfig,
         "max_iterations": cfg.max_iterations, "damping": cfg.damping,
         "kappa": cfg.kappa, "s_mode": cfg.s_mode, "stop": cfg.stop,
         "patience": cfg.patience, "workers": workers, "exchange": exchange,
+        "carry": ["col"],
     }
 
 
@@ -130,7 +134,7 @@ def _topk_segment(s3k, idx, carry, until, *, max_iterations, damping,
     ``(state, e_prev, stable, it, trace)``."""
     s3k = s3k.astype(jnp.float32)
     levels, n, _ = s3k.shape
-    init = hap.hap_init(s3k)
+    init = topk.init_state(s3k)
     sweep, assign = topk.make_topk_sweep(idx, damping=damping, kappa=kappa,
                                          s_mode=s_mode)
     return dense.drive_sweeps(
@@ -141,14 +145,24 @@ def _topk_segment(s3k, idx, carry, until, *, max_iterations, damping,
 
 def _carry_tree(state: hap.HAPState, e, stable, it, trace) -> dict:
     return {"s": state.s, "r": state.r, "a": state.a, "tau": state.tau,
-            "phi": state.phi, "c": state.c, "e_prev": e,
+            "phi": state.phi, "c": state.c, "col": state.col, "e_prev": e,
             "stable": stable, "it": it, "trace": trace}
 
 
-def _carry_like() -> dict:
+def _carry_like(levels: int) -> dict:
+    """The saved tree's structure: ``col`` is None (no leaf) at L = 1."""
     z = np.int32(0)
-    return {k: z for k in ("s", "r", "a", "tau", "phi", "c", "e_prev",
-                           "stable", "it", "trace")}
+    like = {k: z for k in ("s", "r", "a", "tau", "phi", "c", "col",
+                           "e_prev", "stable", "it", "trace")}
+    if levels == 1:
+        like["col"] = None
+    return like
+
+
+def _restored_state(restored: dict) -> hap.HAPState:
+    return hap.HAPState(*(
+        None if restored[f] is None else jnp.asarray(restored[f])
+        for f in hap.HAPState._fields))
 
 
 def _segment_bounds(cfg: SolveConfig):
@@ -182,7 +196,7 @@ def _open_run(cfg: SolveConfig, meta: dict):
         check_meta(cfg.resume_from, meta)
         mgr_in = CheckpointManager(cfg.resume_from, keep=2,
                                    async_save=False)
-        hit = mgr_in.restore_latest(_carry_like())
+        hit = mgr_in.restore_latest(_carry_like(cfg.levels))
         if hit is None:
             raise ValueError(
                 f"resume_from={cfg.resume_from!r} holds no step_* "
@@ -209,10 +223,7 @@ def _run_single_checkpointed(s3k, idx, cfg: SolveConfig):
     carry = None
     it = stable = 0
     if restored is not None:
-        state = hap.HAPState(
-            s=jnp.asarray(restored["s"]), r=jnp.asarray(restored["r"]),
-            a=jnp.asarray(restored["a"]), tau=jnp.asarray(restored["tau"]),
-            phi=jnp.asarray(restored["phi"]), c=jnp.asarray(restored["c"]))
+        state = _restored_state(restored)
         carry = (state, jnp.asarray(restored["e_prev"]),
                  jnp.int32(restored["stable"]), jnp.int32(restored["it"]),
                  jnp.asarray(restored["trace"]))
@@ -232,10 +243,7 @@ def _run_single_checkpointed(s3k, idx, cfg: SolveConfig):
 
     if carry is None:
         # resumed an already-finished run: report it straight from disk
-        state = hap.HAPState(
-            s=jnp.asarray(restored["s"]), r=jnp.asarray(restored["r"]),
-            a=jnp.asarray(restored["a"]), tau=jnp.asarray(restored["tau"]),
-            phi=jnp.asarray(restored["phi"]), c=jnp.asarray(restored["c"]))
+        state = _restored_state(restored)
         e, trace = jnp.asarray(restored["e_prev"]), \
             jnp.asarray(restored["trace"])
     else:
@@ -309,14 +317,14 @@ def _run_sharded_checkpointed(s3k, idx, mesh, cfg: SolveConfig):
 def _repad_carry(restored: dict, s3k_host: np.ndarray, n_real: int,
                  n_total: int, levels: int, mesh):
     """Rebuild the padded sharded carry from a logical checkpoint: real
-    rows from disk, dummy rows reset to their ``hap_init`` values (inert
-    by construction — self-referencing edges, masked change counter — so
-    real-row evolution is unchanged)."""
+    rows from disk, dummy rows reset to their ``init_state`` values
+    (inert by construction — self-referencing edges, masked change
+    counter — so real-row evolution is unchanged)."""
     from repro.sharding.partitioning import device_put_row_sharded
 
     def pad_field(name, init_fill):
         saved = np.asarray(restored[name])
-        full_shape = (levels, n_total) + saved.shape[2:]
+        full_shape = (saved.shape[0], n_total) + saved.shape[2:]
         full = np.full(full_shape, init_fill, saved.dtype)
         full[:, :n_real] = saved
         return full
@@ -326,7 +334,8 @@ def _repad_carry(restored: dict, s3k_host: np.ndarray, n_real: int,
     state = hap.HAPState(
         s=s_full, r=pad_field("r", 0.0), a=pad_field("a", 0.0),
         tau=pad_field("tau", np.inf), phi=pad_field("phi", 0.0),
-        c=pad_field("c", 0.0))
+        c=pad_field("c", 0.0),
+        col=None if levels == 1 else pad_field("col", 0.0))
     e_saved = np.asarray(restored["e_prev"])
     dummies = np.broadcast_to(
         np.arange(n_real, n_total, dtype=e_saved.dtype),

@@ -203,7 +203,10 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
 
     One definition shared by ``run_topk`` and the checkpointed segment
     runner (``repro.solver.checkpointing``) — both must execute the
-    identical op sequence per sweep for resume to be bit-exact.
+    identical op sequence per sweep for resume to be bit-exact. Both
+    start from ``init_state``: at L >= 2 the sweep carries alpha's
+    column sums of levels 0..L-2 to the next sweep's tau
+    (``hap.jacobi_sweep``), one scatter a level a sweep.
 
     ``idx`` is one (N, kk) column map, or a tuple of (N_b, kk_b) row
     blocks (then s/r/a are tuples of (L, N_b, kk_b) blocks): the row-wise
@@ -236,10 +239,10 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
     def level(x, l):
         return like(x, tuple(b[l] for b in as_blocks(x)))
 
-    def tau_red(r, c):                       # (L-1, N, kk), (L-1, N)
-        if c.shape[0] == 0:                  # L=1: no level above
+    def tau_red(r, c, col=None):             # (L-1, N, kk), (L-1, N) x 2
+        if col is None:                      # L=1: no level above
             return jnp.zeros(c.shape, c.dtype)
-        return jnp.stack([tau_topk(level(r, l), c[l], idx)
+        return jnp.stack([tau_topk(level(r, l), c[l], col[l], idx)
                           for l in range(c.shape[0])])
 
     reducers = hap.SweepReducers(
@@ -257,11 +260,15 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
             s, a, split_rows(tau), r))
 
     def update_a(r, c, phi, a):
-        new = [as_blocks(alpha_topk(level(r, l), c[l], phi[l], idx))
-               for l in range(c.shape[0])]
-        return like(a, tuple(
-            hap._damp(a_b, jnp.stack([lv[b] for lv in new]), damping)
+        new, cols = zip(*(alpha_topk(level(r, l), c[l], phi[l], idx)
+                          for l in range(c.shape[0])))
+        a = like(a, tuple(
+            hap._damp(a_b, jnp.stack([as_blocks(lv)[b] for lv in new]),
+                      damping)
             for b, a_b in enumerate(as_blocks(a))))
+        if len(cols) == 1:                   # L=1: nothing carried
+            return a
+        return a, jnp.stack(cols[:-1])       # levels 0..L-2, for tau
 
     def sweep(state, it):
         return hap.jacobi_sweep(
@@ -276,6 +283,16 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
             state.a, state.r, idx))
 
     return sweep, assign
+
+
+def init_state(s3k) -> hap.HAPState:
+    """``hap.hap_init`` plus the column sums the sparse loop carries:
+    (L-1, N) zeros, the scatter of rho = 0 (None at L = 1)."""
+    init = hap.hap_init(s3k)
+    levels, n = init.c.shape
+    if levels == 1:
+        return init
+    return init._replace(col=jnp.zeros((levels - 1, n), init.c.dtype))
 
 
 def run_topk(
@@ -297,7 +314,7 @@ def run_topk(
     ``(state, exemplars, n_sweeps, converged, trace)``.
     """
     s3k = jax.tree.map(lambda b: b.astype(jnp.float32), s3k)
-    init = hap.hap_init(s3k)
+    init = init_state(s3k)
     levels, n = init.c.shape
     sweep, assign = make_topk_sweep(idx, damping=damping, kappa=kappa,
                                     s_mode=s_mode)

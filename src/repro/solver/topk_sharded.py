@@ -16,7 +16,9 @@ Per-sweep dataflow on each worker (B = N/W local rows):
   ``repro.kernels.topk_ops``.
 * the availability/tau column statistics (Eqs 2.2-2.4) sum max(0, rho)
   over *incoming* edges, whose sources live on other workers. That one
-  primitive becomes an explicit exchange (``SolveConfig.exchange``):
+  primitive becomes an explicit exchange (``SolveConfig.exchange``), made
+  once a level a sweep in the alpha update; each worker carries its rows
+  of the exchanged sums to the next sweep's tau, which exchanges nothing:
 
   ``allgather`` — workers all-gather the (B, k+1) rho blocks and re-run
   the oracle's own scatter over the full edge set. Accumulation order is
@@ -63,9 +65,10 @@ from repro.kernels.topk_ops import (
     alpha_from_stats, assignments_topk, c_topk, col_partial_topk,
     col_stats_topk, phi_topk, rho_topk, s_next_topk, tau_from_stats,
 )
+from repro.runtime.trace import SCOPE_GATHER
 from repro.sharding.partitioning import device_put_row_sharded
 from repro.solver import dense
-from repro.solver.topk import TopKState
+from repro.solver.topk import TopKState, init_state
 
 AXIS = "workers"
 
@@ -143,12 +146,13 @@ def comm_bytes_per_sweep(n: int, k: int, levels: int, workers: int,
 
     Both exchanges pay the O(L*N) statistics gathers (base = c + phi per
     level, rdiag + the change counter); allgather additionally moves the
-    (N, k+1) rho blocks for every column-statistics evaluation (twice
-    per sweep: tau on levels 0..L-2, alpha on all levels), psum an (N,)
-    partial each. Ring collectives move ~2*(W-1)/W * payload cluster-wide.
+    (N, k+1) rho blocks for every column-statistics evaluation (once a
+    level a sweep, in alpha: tau reads the sums alpha exchanged at the
+    previous sweep), psum an (N,) partial each. Ring collectives move
+    ~2*(W-1)/W * payload cluster-wide.
     """
     ring = 2 * (workers - 1) * bytes_per_el
-    stats_calls = (levels - 1) + levels            # tau + alpha evaluations
+    stats_calls = levels                           # alpha's evaluations
     small = (levels + stats_calls) * n * ring      # base gathers + rdiag/psum
     if exchange == "psum":
         return small + stats_calls * n * ring      # the (N,) partial psums
@@ -192,13 +196,11 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
             rdiag = jax.lax.all_gather(r_l[:, 0], AXIS, axis=0, tiled=True)
             return col, rdiag
 
-        def tau_red(r_lv, c_lv):                   # (L-1, B, kk), (L-1, B)
+        def tau_red(r_lv, c_lv, col=None):         # (L-1, B, kk), (L-1, B)x2
             if levels == 1:
                 return jnp.zeros((0, n_local), s_loc.dtype)
-            return jnp.stack([
-                tau_from_stats(c_lv[l], r_lv[l][:, 0],
-                               col_stats(r_lv[l])[0][rows])
-                for l in range(levels - 1)])
+            return jnp.stack([tau_from_stats(c_lv[l], r_lv[l][:, 0], col[l])
+                              for l in range(levels - 1)])
 
         reducers = hap.SweepReducers(
             tau=tau_red,
@@ -212,13 +214,18 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
             return hap._damp(r, jax.vmap(rho_topk)(s, a, tau), damping)
 
         def update_a(r, c, phi, a):
-            new = []
+            new, cols = [], []
             for l in range(levels):                # L static: unrolled
                 col, rdiag = col_stats(r[l])
                 base = jax.lax.all_gather(c[l] + phi[l], AXIS, axis=0,
                                           tiled=True)
                 new.append(alpha_from_stats(r[l], idx_loc, col, base, rdiag))
-            return hap._damp(a, jnp.stack(new), damping)
+                cols.append(col)
+            a = hap._damp(a, jnp.stack(new), damping)
+            if levels == 1:
+                return a
+            with jax.named_scope(SCOPE_GATHER):    # the rows tau reads
+                return a, jnp.stack([col[rows] for col in cols[:-1]])
 
         def sweep(state, it):
             return hap.jacobi_sweep(
@@ -231,12 +238,12 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
                                                 n_total=n_total)
             )(state.a, state.r)
 
-        init = hap.hap_init(s_loc)
-        # tau/phi/c come out of hap_init as fresh constants; the loop
-        # carries device-varying replacements, so mark them up front.
+        init = init_state(s_loc)
+        # tau/phi/c/col come out of init_state as fresh constants; the
+        # loop carries device-varying replacements, so mark them up front.
         vary = lambda x: jax.lax.pcast(x, (AXIS,), to="varying")
         init = init._replace(tau=vary(init.tau), phi=vary(init.phi),
-                             c=vary(init.c))
+                             c=vary(init.c), col=jax.tree.map(vary, init.col))
         scal = lambda v: vary(jnp.reshape(v, (1,)))
 
         if not segmented:
@@ -265,7 +272,8 @@ def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
     row3 = P(None, AXIS, None)
     row2 = P(None, AXIS)
     state_spec = hap.HAPState(s=row3, r=row3, a=row3,
-                              tau=row2, phi=row2, c=row2)
+                              tau=row2, phi=row2, c=row2,
+                              col=None if levels == 1 else row2)
     in_specs = [row3, P(AXIS, None)]
     if segmented:
         in_specs.append(P(None))                   # until

@@ -182,6 +182,28 @@ def test_resume_rejects_mismatched_config(tmp_path):
         solve(x, cfg.replace(resume_from=d, damping=0.8))
 
 
+def test_resume_refuses_checkpoint_without_carried_sums(tmp_path):
+    """A checkpoint written before the loop carried alpha's column sums
+    (its meta has no ``carry`` entry) is refused, not resumed."""
+    import json
+    import os
+
+    x = _pts()
+    d = str(tmp_path / "ck")
+    cfg = SolveConfig(backend="dense_topk", k=16, levels=3,
+                      max_iterations=20, stop="fixed", preference="median",
+                      checkpoint_every=4, checkpoint_dir=d)
+    solve(x, cfg)
+    path = os.path.join(d, checkpointing.META_NAME)
+    with open(path) as f:
+        meta = json.load(f)
+    assert meta.pop("carry") == ["col"]
+    with open(path, "w") as f:
+        json.dump(meta, f)
+    with pytest.raises(ValueError, match="carry"):
+        solve(x, cfg.replace(resume_from=d))
+
+
 def test_checkpoint_config_validation():
     x = _pts(n=32)
     with pytest.raises(ValueError, match="checkpoint_every"):

@@ -7,6 +7,10 @@ adding the gathered blocks in the same order (the formula written out
 below, in numpy), for the one-device layout and for a row block of the
 sharded sweep; and one sweep must hold exactly one (N, kk) gather per
 level, so the three gathers cannot come back unnoticed.
+
+Likewise one sweep holds one column-sum scatter-add a level per row
+block: alpha's, whose sums tau reads at the next sweep
+(``hap.jacobi_sweep``); tau scattering the same rho again made 2L - 1.
 """
 import re
 
@@ -15,9 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import hap
 from repro.kernels.topk_ops import alpha_from_stats
-from repro.solver.topk import make_topk_sweep
+from repro.solver.topk import init_state, make_topk_sweep
 
 N, KK = 64, 9
 
@@ -63,14 +66,47 @@ def test_alpha_from_stats_equals_three_gathers(rows, kind):
     assert np.array_equal(np.asarray(got), want)
 
 
+def _sweep_text(s3k, idx):
+    """One sweep after the first, lowered."""
+    sweep, _ = make_topk_sweep(idx, damping=0.7, kappa=0.0, s_mode="off")
+    return jax.jit(sweep).lower(init_state(s3k), jnp.int32(1)).as_text()
+
+
 def test_sweep_gathers_once_per_level():
     levels = 3
     rng = np.random.default_rng(3)
     idx = jnp.asarray(_idx(rng))
     s3k = jnp.asarray(np.broadcast_to(_values(rng, (N, KK), "normal"),
                                       (levels, N, KK)))
-    sweep, _ = make_topk_sweep(idx, damping=0.7, kappa=0.0, s_mode="off")
-    text = jax.jit(sweep).lower(hap.hap_init(s3k), jnp.int32(1)).as_text()
+    text = _sweep_text(s3k, idx)
     gathers = re.findall(r'"?stablehlo\.gather"?.*-> tensor<(\S+)>', text)
     assert gathers
     assert gathers.count(f"{N}x{KK}xf32") == levels
+
+
+#: a scatter whose region adds (not the self slot's set), and its result
+SCATTER = re.compile(r'"stablehlo\.scatter".*\n.*\^bb0.*\n\s*(?:%\S+ = )?'
+                     r'stablehlo\.(\w+)[^\n]*\n(?:.*\n)*?\s*\}\) : '
+                     r'.*-> tensor<(\S+)>')
+
+
+@pytest.mark.parametrize("levels", [1, 2, 3])
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one_layout", "row_blocks"])
+def test_sweep_scatters_once_per_level(levels, blocks):
+    """L column-sum scatter-adds a sweep on one layout, L per block on
+    row blocks (the second block keeps 5 of its rows' 9 slots)."""
+    rng = np.random.default_rng(3)
+    idx, vals = _idx(rng), _values(rng, (N, KK), "normal")
+    if blocks == 2:
+        h = N // 2
+        idx, vals = (idx[:h], idx[h:, :5]), (vals[:h], vals[h:, :5])
+    else:
+        idx, vals = (idx,), (vals,)
+    s3k = tuple(jnp.asarray(np.broadcast_to(v, (levels, *v.shape)))
+                for v in vals)
+    idx = tuple(map(jnp.asarray, idx))
+    if blocks == 1:
+        s3k, idx = s3k[0], idx[0]
+    found = SCATTER.findall(_sweep_text(s3k, idx))
+    adds = [shape for op, shape in found if op == "add"]
+    assert adds == [f"{N}xf32"] * (levels * blocks)
