@@ -5,26 +5,30 @@ module closes that gap for the two long-running backends
 (``SolveConfig.checkpoint_every`` / ``checkpoint_dir`` /
 ``resume_from``):
 
-* **dense_topk** (single-device and ``sweep="sharded"``) — the Jacobi
-  loop runs as *segments* of the same ``lax.while_loop``
-  (``dense.drive_sweeps(segmented=True)``); between segments the host
-  snapshots the compressed message state, the column sums the loop
-  carries from alpha to the next sweep's tau (``col``) and the sweep
-  index through ``repro.checkpoint``. The segment bound ``until`` is a
-  *dynamic* operand, so a whole solve compiles exactly two programs
-  (fresh first segment, resumed segments) no matter how many boundaries
-  it crosses. Because checkpointed runs always execute the segmented
-  program, an interrupted-and-resumed run and an uninterrupted
-  checkpointed run are the *same op sequence with the same inputs* —
-  resume is bit-exact by construction, and the tests additionally
-  assert equality against the plain un-checkpointed solve.
+* **dense_topk** (single-device and ``sweep="sharded"``) — one segment
+  runner (``_run_segments``) for both placements: the Jacobi loop runs
+  as *segments* of the same ``lax.while_loop`` (``topk.sweep_loop`` with
+  an ``until``); between segments the host snapshots the compressed
+  message state, the column sums the loop carries from alpha to the
+  next sweep's tau (``col``) and the sweep index through
+  ``repro.checkpoint``. A placement supplies its segment program
+  (``topk.run_segment``, or the sharded program given ``until``), how a
+  carry is saved and how a saved one is restored. The segment bound
+  ``until`` is a *dynamic* operand, so a whole solve compiles exactly
+  two programs (fresh first segment, resumed segments) no matter how
+  many boundaries it crosses. Because checkpointed runs always execute
+  the segmented program, an interrupted-and-resumed run and an
+  uninterrupted checkpointed run are the *same op sequence with the
+  same inputs* — resume is bit-exact by construction, and the tests
+  additionally assert equality against the plain un-checkpointed solve.
 
-* **sharded sweeps** store the *unpadded logical* state. On resume the
-  rows are re-padded with fresh inert dummies (``pad_topk``'s dummies
-  only self-reference, and the change counter masks them out), so real
-  rows evolve bit-identically even though dummy rows restart — and a
-  resume onto a different worker count would even be legal, though the
-  engine currently resumes onto the same mesh.
+* **sharded sweeps** save the *unpadded logical* state. On resume the
+  rows are re-padded with fresh inert dummies (``_repad_carry``;
+  ``pad_topk``'s dummies only self-reference, and the change counter
+  masks them out), so real rows evolve bit-identically even though
+  dummy rows restart — and a resume onto a different worker count would
+  even be legal, though the engine currently resumes onto the same
+  mesh.
 
 * **coarsen** — per-stage artifacts instead of sweep segments: the
   deterministic kd partition is recomputed, the local-solve loop
@@ -57,7 +61,8 @@ import numpy as np
 from repro.checkpoint.ckpt import CheckpointManager, restore_tree, save_tree
 from repro.core import hap
 from repro.runtime import faultinject
-from repro.solver import dense, topk
+from repro.sharding.partitioning import device_put_row_sharded
+from repro.solver import topk
 from repro.solver import topk_sharded as ts
 from repro.solver.config import SolveConfig
 from repro.solver.topk import TopKState
@@ -119,30 +124,7 @@ def _topk_meta(kind: str, n: int, kk: int, cfg: SolveConfig,
     }
 
 
-# --------------------------------------------------- single-device segments
-@functools.partial(
-    jax.jit,
-    static_argnames=("max_iterations", "damping", "kappa", "s_mode",
-                     "stop", "patience"))
-def _topk_segment(s3k, idx, carry, until, *, max_iterations, damping,
-                  kappa, s_mode, stop, patience):
-    """One checkpoint segment of the single-device sparse loop.
-
-    ``until`` is a traced sweep index and ``carry`` the raw loop carry
-    from the previous segment (None = fresh start) — two compilations
-    per config total. Returns the raw carry
-    ``(state, e_prev, stable, it, trace)``."""
-    s3k = s3k.astype(jnp.float32)
-    levels, n, _ = s3k.shape
-    init = topk.init_state(s3k)
-    sweep, assign = topk.make_topk_sweep(idx, damping=damping, kappa=kappa,
-                                         s_mode=s_mode)
-    return dense.drive_sweeps(
-        init, sweep, assign, levels, n, max_iterations=max_iterations,
-        stop=stop, patience=patience, segmented=True, carry=carry,
-        until=until)
-
-
+# ------------------------------------------------------------ sweep segments
 def _carry_tree(state: hap.HAPState, e, stable, it, trace) -> dict:
     return {"s": state.s, "r": state.r, "a": state.a, "tau": state.tau,
             "phi": state.phi, "c": state.c, "col": state.col, "e_prev": e,
@@ -159,17 +141,15 @@ def _carry_like(levels: int) -> dict:
     return like
 
 
-def _restored_state(restored: dict) -> hap.HAPState:
-    return hap.HAPState(*(
+def _restored_carry(restored: dict):
+    """A saved tree -> the loop carry ``(state, e_prev, stable, it,
+    trace)`` of one device."""
+    state = hap.HAPState(*(
         None if restored[f] is None else jnp.asarray(restored[f])
         for f in hap.HAPState._fields))
-
-
-def _segment_bounds(cfg: SolveConfig):
-    """(every, max_iterations) with every<=0 meaning one segment."""
-    every = cfg.checkpoint_every
-    mi = cfg.max_iterations
-    return every, mi
+    return (state, jnp.asarray(restored["e_prev"]),
+            jnp.int32(restored["stable"]), jnp.int32(restored["it"]),
+            jnp.asarray(restored["trace"]))
 
 
 def _is_done(it: int, stable: int, cfg: SolveConfig) -> bool:
@@ -182,10 +162,45 @@ def run_topk_checkpointed(s3k, idx, cfg: SolveConfig, *, mesh=None):
 
     Same return contract: ``(TopKState, exemplars, n_sweeps, converged,
     trace)`` (exemplars in the padded N' when sharded — the engine
-    strips dummies)."""
-    if mesh is not None:
-        return _run_sharded_checkpointed(s3k, idx, mesh, cfg)
-    return _run_single_checkpointed(s3k, idx, cfg)
+    strips dummies). A placement supplies its segment program
+    ``(until, carry) -> carry``, how a carry is saved and how a saved
+    one is restored; ``_run_segments`` drives both."""
+    s3k = s3k.astype(jnp.float32)
+    _, n, kk = s3k.shape
+    loop = {k: getattr(cfg, k) for k in topk.LOOP_ARGS}
+    if mesh is None:
+        kind, meta = "single", _topk_meta("dense_topk_single", n, kk, cfg,
+                                          1, None)
+        segment = functools.partial(topk.run_segment, s3k, idx, **loop)
+        save, restore = _carry_tree, _restored_carry
+    else:
+        kind, w = "sharded", mesh.shape[ts.AXIS]
+        s3k_p, idx_p, n_real = ts.pad_topk(s3k, idx, w)
+        exchange = ts.resolve_exchange(cfg.exchange, n=s3k_p.shape[1],
+                                       kk=kk)
+        meta = _topk_meta("dense_topk_sharded", n, kk, cfg, w, exchange)
+        s3k_host = np.asarray(s3k_p)
+        idx = device_put_row_sharded(idx_p, mesh, ts.AXIS, axis=0)
+        segment = functools.partial(
+            ts._sharded_program(mesh, n_real, exchange, **loop),
+            device_put_row_sharded(s3k_p, mesh, ts.AXIS, axis=1), idx)
+
+        def logical(a):            # the unpadded rows: dummies are re-derived
+            return np.asarray(a)[:, :n_real]
+
+        def save(state, e, stable, it, trace):
+            return _carry_tree(jax.tree.map(logical, state), logical(e),
+                               np.int32(stable), np.int32(it),
+                               np.asarray(trace))
+
+        def restore(restored):
+            return _repad_carry(restored, s3k_host, n_real, mesh)
+
+    carry, it, stable = _run_segments(cfg, meta, kind, segment, save,
+                                      restore)
+    state, e, _, _, trace = carry
+    return (TopKState(state, idx), e, jnp.int32(it),
+            jnp.asarray(stable >= cfg.patience), jnp.asarray(trace))
 
 
 def _open_run(cfg: SolveConfig, meta: dict):
@@ -214,113 +229,35 @@ def _open_run(cfg: SolveConfig, meta: dict):
     return mgr, restored
 
 
-def _run_single_checkpointed(s3k, idx, cfg: SolveConfig):
-    levels, n, kk = s3k.shape
-    meta = _topk_meta("dense_topk_single", n, kk, cfg, 1, None)
+def _run_segments(cfg: SolveConfig, meta: dict, kind: str, segment, save,
+                  restore):
+    """The one segment loop: restore the latest checkpoint if resuming,
+    then run ``segment`` up to each boundary, save the carry and fire the
+    ``solver.sweep`` fault site (``kind`` names the placement) until the
+    stopping rule holds. -> ``(carry, it, stable)``; a resumed run that
+    had already finished returns the restored carry."""
     mgr, restored = _open_run(cfg, meta)
-    every, mi = _segment_bounds(cfg)
-
-    carry = None
-    it = stable = 0
+    carry, it, stable = None, 0, 0
     if restored is not None:
-        state = _restored_state(restored)
-        carry = (state, jnp.asarray(restored["e_prev"]),
-                 jnp.int32(restored["stable"]), jnp.int32(restored["it"]),
-                 jnp.asarray(restored["trace"]))
+        carry = restore(restored)
         it, stable = int(restored["it"]), int(restored["stable"])
-
+    every, mi = cfg.checkpoint_every, cfg.max_iterations
     while not _is_done(it, stable, cfg):
         until = mi if every <= 0 else min(it + every, mi)
-        carry = _topk_segment(
-            s3k, idx, carry, jnp.int32(until),
-            max_iterations=mi, damping=cfg.damping, kappa=cfg.kappa,
-            s_mode=cfg.s_mode, stop=cfg.stop, patience=cfg.patience)
-        state, e, stable_a, it_a, trace = carry
-        it, stable = int(it_a), int(stable_a)
+        carry = segment(jnp.int32(until), carry)
+        it, stable = int(carry[3]), int(carry[2])
         if mgr is not None:
-            mgr.save(it, _carry_tree(state, e, stable_a, it_a, trace))
-        faultinject.fire("solver.sweep", sweep=it, kind="single")
-
-    if carry is None:
-        # resumed an already-finished run: report it straight from disk
-        state = _restored_state(restored)
-        e, trace = jnp.asarray(restored["e_prev"]), \
-            jnp.asarray(restored["trace"])
-    else:
-        state, e, _, _, trace = carry
-    return (TopKState(state, idx), e, jnp.int32(it),
-            jnp.asarray(stable >= cfg.patience), trace)
+            mgr.save(it, save(*carry))
+        faultinject.fire("solver.sweep", sweep=it, kind=kind)
+    return carry, it, stable
 
 
-# --------------------------------------------------------- sharded segments
-def _run_sharded_checkpointed(s3k, idx, mesh, cfg: SolveConfig):
-    from repro.sharding.partitioning import device_put_row_sharded
-
-    s3k = s3k.astype(jnp.float32)
-    levels, n, kk = s3k.shape
-    w = mesh.shape[ts.AXIS]
-    s3k_p, idx_p, n_real = ts.pad_topk(s3k, idx, w)
-    n_total = s3k_p.shape[1]
-    exchange = ts.resolve_exchange(cfg.exchange, n=n_total, kk=kk)
-    meta = _topk_meta("dense_topk_sharded", n, kk, cfg, w, exchange)
-    mgr, restored = _open_run(cfg, meta)
-    every, mi = _segment_bounds(cfg)
-
-    s3k_host = np.asarray(s3k_p)
-    s3k_p = device_put_row_sharded(s3k_p, mesh, ts.AXIS, axis=1)
-    idx_p = device_put_row_sharded(idx_p, mesh, ts.AXIS, axis=0)
-    base = (mesh, levels, n_total // w, n_total, n_real, kk, mi,
-            cfg.damping, cfg.kappa, cfg.s_mode, cfg.stop, cfg.patience,
-            exchange, True)
-    fresh_fn = ts._sharded_program(*base, False)
-    cont_fn = ts._sharded_program(*base, True)
-
-    carry = None            # (state, e, stable_arr1, it_arr1, trace)
-    it = stable = 0
-    if restored is not None:
-        carry = _repad_carry(restored, s3k_host, n_real, n_total, levels,
-                             mesh)
-        it, stable = int(restored["it"]), int(restored["stable"])
-
-    while not _is_done(it, stable, cfg):
-        until = mi if every <= 0 else min(it + every, mi)
-        until_a = jnp.full((1,), until, jnp.int32)
-        if carry is None:
-            state, e, stable_w, it_w, trace_w = fresh_fn(
-                s3k_p, idx_p, until_a)
-        else:
-            state, e, stable_w, it_w, trace_w = cont_fn(
-                s3k_p, idx_p, until_a, *carry)
-        it, stable = int(it_w[0]), int(stable_w[0])
-        trace = trace_w[0]
-        carry = (state, e, jnp.full((1,), stable, jnp.int32),
-                 jnp.full((1,), it, jnp.int32), trace)
-        if mgr is not None:
-            # store the unpadded logical rows — dummies are re-derived
-            logical = jax.tree.map(
-                lambda a: np.asarray(a)[:, :n_real], state)
-            tree = _carry_tree(logical, np.asarray(e)[:, :n_real],
-                               np.int32(stable), np.int32(it),
-                               np.asarray(trace))
-            mgr.save(it, tree)
-        faultinject.fire("solver.sweep", sweep=it, kind="sharded")
-
-    if carry is None:
-        # resumed an already-finished run
-        carry = _repad_carry(restored, s3k_host, n_real, n_total, levels,
-                             mesh)
-    state, e, _, _, trace = carry
-    return (TopKState(state, jnp.asarray(idx_p)), e, jnp.int32(it),
-            jnp.asarray(stable >= cfg.patience), jnp.asarray(trace))
-
-
-def _repad_carry(restored: dict, s3k_host: np.ndarray, n_real: int,
-                 n_total: int, levels: int, mesh):
+def _repad_carry(restored: dict, s3k_host: np.ndarray, n_real: int, mesh):
     """Rebuild the padded sharded carry from a logical checkpoint: real
     rows from disk, dummy rows reset to their ``init_state`` values
     (inert by construction — self-referencing edges, masked change
     counter — so real-row evolution is unchanged)."""
-    from repro.sharding.partitioning import device_put_row_sharded
+    levels, n_total = s3k_host.shape[:2]
 
     def pad_field(name, init_fill):
         saved = np.asarray(restored[name])
@@ -341,15 +278,11 @@ def _repad_carry(restored: dict, s3k_host: np.ndarray, n_real: int,
         np.arange(n_real, n_total, dtype=e_saved.dtype),
         (levels, n_total - n_real))
     e_full = np.concatenate([e_saved, dummies], axis=1)
-    state = jax.tree.map(
+    state, e_full = jax.tree.map(
         lambda a: device_put_row_sharded(jnp.asarray(a), mesh, ts.AXIS,
-                                         axis=1), state)
-    e_full = device_put_row_sharded(jnp.asarray(e_full), mesh, ts.AXIS,
-                                    axis=1)
-    return (state, e_full,
-            jnp.full((1,), int(restored["stable"]), jnp.int32),
-            jnp.full((1,), int(restored["it"]), jnp.int32),
-            jnp.asarray(restored["trace"]))
+                                         axis=1), (state, e_full))
+    return (state, e_full, jnp.int32(restored["stable"]),
+            jnp.int32(restored["it"]), jnp.asarray(restored["trace"]))
 
 
 # ------------------------------------------------------------ coarsen stage

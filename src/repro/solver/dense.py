@@ -84,7 +84,7 @@ def _assignments(state: hap.HAPState) -> jnp.ndarray:
 def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
                  max_iterations: int, stop: str, patience: int,
                  count_mask=None, axis_name: str | None = None,
-                 segmented: bool = False, carry=None, until=None):
+                 carry=None, until=None):
     """The one stopping-rule loop every single-device backend shares.
 
     ``sweep(state, it) -> state`` and ``assign(state) -> (L, N) int32``
@@ -104,17 +104,16 @@ def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
     ((n,) bool) drops padding rows from the count, keeping the trace
     bit-identical to the unpadded oracle's.
 
-    Checkpointed callers (``repro.solver.checkpointing``) set
-    ``segmented=True`` to run one *segment* of the loop: ``carry`` is the
-    raw while_loop carry ``(state, e_prev, stable, it, trace)`` from the
-    previous segment (None = start fresh), ``until`` is a (possibly
-    traced) sweep index to pause at, and the return value is the raw
-    carry rather than the finished ``(state, e, n_sweeps, converged,
-    trace)`` contract. Segments always take the while_loop path — also
-    for ``stop="fixed"``, where the patience condition is disabled — so
-    the checkpointed program is the *same* op sequence regardless of
-    where the segment boundaries fall, which is what makes resume
-    bit-exact by construction.
+    Checkpointed callers (``repro.solver.checkpointing``) pass ``until``,
+    a (possibly traced) sweep index to pause at, to run one *segment* of
+    the loop: ``carry`` is the raw while_loop carry ``(state, e_prev,
+    stable, it, trace)`` from the previous segment (None = start fresh),
+    and the return value is the raw carry rather than the finished
+    ``(state, e, n_sweeps, converged, trace)`` contract. Segments always
+    take the while_loop path — also for ``stop="fixed"``, where the
+    patience condition is disabled — so the checkpointed program is the
+    *same* op sequence regardless of where the segment boundaries fall,
+    which is what makes resume bit-exact by construction.
     """
     e0 = jnp.full((levels, n), -1, jnp.int32)
     if axis_name is not None:
@@ -130,6 +129,7 @@ def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
             changed = jax.lax.psum(changed, axis_name)
         return changed
 
+    segmented = until is not None
     if stop == "fixed" and not segmented:
         def step(carry, it):
             state, e_prev = carry
@@ -148,7 +148,7 @@ def drive_sweeps(init, sweep, assign, levels: int, n: int, *,
     # stop="fixed" disable the patience exit and bound the loop by
     # ``until`` instead of max_iterations.
     patience_eff = patience if stop == "converged" else max_iterations + 1
-    until_val = jnp.int32(max_iterations if until is None else until)
+    until_val = jnp.int32(until if segmented else max_iterations)
     trace0 = jnp.full((max_iterations,), -1, jnp.int32)
 
     def cond(carry):

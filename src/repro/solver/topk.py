@@ -10,6 +10,13 @@ updates and reducers injected — and the stopping loop is the same
 ``drive_sweeps`` the dense driver uses, so fixed budgets, convergence
 early-exit, and the per-sweep trace all carry over unchanged.
 
+One sweep, one loop: ``make_topk_sweep`` is the only place the sparse
+sweep is composed and ``sweep_loop`` the only sparse loop program. A
+placement supplies what differs — one device (``run_topk``,
+``run_segment``) or a row shard (``repro.solver.topk_sharded``) its
+column ``Exchange`` — and both carry each row's own column sums from
+alpha to the next sweep's tau.
+
 Exactness contract: a dropped edge is a -inf similarity, under which the
 sparse updates equal the dense updates restricted to stored positions.
 At ``k = N - 1`` (full coverage) ``run_topk`` therefore reproduces
@@ -19,7 +26,7 @@ which holds exemplar quality to within a couple of purity points.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,11 +34,11 @@ import jax.numpy as jnp
 from repro.core import hap
 from repro.core.preferences import random_preference
 from repro.kernels.topk_ops import (
-    alpha_topk, as_blocks, assignments_topk, c_topk, join_rows, phi_topk,
-    rho_topk, s_next_topk, tau_topk,
+    alpha_from_stats, as_blocks, assignments_topk, c_topk, col_stats_topk,
+    join_rows, phi_topk, rho_topk, s_next_topk, tau_topk,
 )
 from repro.kernels.topk_similarity import topk_from_dense
-from repro.runtime.trace import SWEEP_PROGRAM
+from repro.runtime.trace import SCOPE_GATHER, SWEEP_PROGRAM
 from repro.solver import dense
 
 #: default neighbors per row (excluding self) when ``SolveConfig.k`` is
@@ -198,31 +205,49 @@ def compress_stack(s3: jnp.ndarray, k: int
     return s3k, idx_full
 
 
-def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
-    """Build the ``(sweep, assign)`` pair for the compressed layout.
+class Exchange(NamedTuple):
+    """What a placement of the rows supplies to the sweep: how one
+    level's column statistics reach its rows. ``n`` is the global column
+    count; ``stats(r_l) -> (col, rdiag)`` gives the full-length (n,)
+    availability column sum and rho's self slots of one level's rho (the
+    placement's rows: one array or row blocks); ``full(v)`` a full-length
+    (n,) copy of one of the placement's (N_local,) vectors."""
+    n: int
+    stats: Callable
+    full: Callable
 
-    One definition shared by ``run_topk`` and the checkpointed segment
-    runner (``repro.solver.checkpointing``) — both must execute the
-    identical op sequence per sweep for resume to be bit-exact. Both
-    start from ``init_state``: at L >= 2 the sweep carries alpha's
-    column sums of levels 0..L-2 to the next sweep's tau
-    (``hap.jacobi_sweep``), one scatter a level a sweep.
+
+def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str,
+                    exchange: Optional[Exchange] = None):
+    """Build the ``(sweep, assign)`` pair for the compressed layout: the
+    one place the sparse sweep is composed.
 
     ``idx`` is one (N, kk) column map, or a tuple of (N_b, kk_b) row
     blocks (then s/r/a are tuples of (L, N_b, kk_b) blocks): the row-wise
     updates run on each block, the column statistics accumulate one (N,)
     sum over them, and (N,) vectors are the blocks' joined in row order.
-    One block is the one-map program, op for op.
+    One block is the one-map program, op for op. ``exchange`` is what
+    the rows' placement supplies: on one device (the default)
+    ``col_stats_topk`` and the identity, on a row shard its collectives
+    (``repro.solver.topk_sharded``); the rows' ids are ``idx[:, 0]``.
 
-    The two updates that scatter and gather through ``idx`` (tau, alpha)
-    run level by level (L is small and static: unrolled) rather than
-    under ``vmap``: a vmapped scatter puts the level axis minor, (N*kk,
-    L), which a TPU pads from L to 128 lanes (40x the state at N=2^18)."""
+    At L >= 2 the sweep carries each row's own column sums of levels
+    0..L-2 (``HAPState.col``, (L-1, N_local)) from alpha to the next
+    sweep's tau (``hap.jacobi_sweep``): alpha reads them out of its
+    full-length sums as ``col[rows]``, and tau is ``c + rdiag + col``,
+    one scatter a level a sweep. A loop starts from ``init_state``.
+
+    Alpha, which scatters and gathers through ``idx``, runs level by
+    level (L is small and static: unrolled) rather than under ``vmap``:
+    a vmapped scatter puts the level axis minor, (N*kk, L), which a TPU
+    pads from L to 128 lanes (40x the state at N=2^18)."""
     blocks = as_blocks(idx)
     bounds = [0]
     for i_b in blocks:
         bounds.append(bounds[-1] + i_b.shape[0])
-    n = bounds[-1]
+    ex = exchange or Exchange(
+        bounds[-1], lambda r_l: col_stats_topk(r_l, idx), lambda v: v)
+    rows = join_rows([i_b[:, 0] for i_b in blocks])  # each row's global id
 
     def per_block(fn, *xs):
         """``fn`` on each block of the row-blocked arguments -> a tuple."""
@@ -242,8 +267,8 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
     def tau_red(r, c, col=None):             # (L-1, N, kk), (L-1, N) x 2
         if col is None:                      # L=1: no level above
             return jnp.zeros(c.shape, c.dtype)
-        return jnp.stack([tau_topk(level(r, l), c[l], col[l], idx)
-                          for l in range(c.shape[0])])
+        return tau_topk(c, join_rows([r_b[:, :, 0] for r_b in as_blocks(r)]),
+                        col)
 
     reducers = hap.SweepReducers(
         tau=tau_red,
@@ -260,15 +285,21 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
             s, a, split_rows(tau), r))
 
     def update_a(r, c, phi, a):
-        new, cols = zip(*(alpha_topk(level(r, l), c[l], phi[l], idx)
-                          for l in range(c.shape[0])))
+        new, cols = [], []
+        for l in range(c.shape[0]):
+            r_l = level(r, l)
+            col, rdiag = ex.stats(r_l)
+            base = ex.full(c[l] + phi[l])
+            new.append([alpha_from_stats(r_b, i_b, col, base, rdiag)
+                        for r_b, i_b in zip(as_blocks(r_l), blocks)])
+            cols.append(col)
         a = like(a, tuple(
-            hap._damp(a_b, jnp.stack([as_blocks(lv)[b] for lv in new]),
-                      damping)
+            hap._damp(a_b, jnp.stack([lv[b] for lv in new]), damping)
             for b, a_b in enumerate(as_blocks(a))))
         if len(cols) == 1:                   # L=1: nothing carried
             return a
-        return a, jnp.stack(cols[:-1])       # levels 0..L-2, for tau
+        with jax.named_scope(SCOPE_GATHER):  # the rows' own, for tau
+            return a, jnp.stack([col[rows] for col in cols[:-1]])
 
     def sweep(state, it):
         return hap.jacobi_sweep(
@@ -278,7 +309,7 @@ def make_topk_sweep(idx, *, damping: float, kappa: float, s_mode: str):
     def assign(state):
         return join_rows(per_block(
             lambda a_b, r_b, i_b: jax.vmap(
-                lambda al, rl: assignments_topk(al, rl, i_b, n_total=n))(
+                lambda al, rl: assignments_topk(al, rl, i_b, n_total=ex.n))(
                     a_b, r_b),
             state.a, state.r, idx))
 
@@ -293,6 +324,43 @@ def init_state(s3k) -> hap.HAPState:
     if levels == 1:
         return init
     return init._replace(col=jnp.zeros((levels - 1, n), init.c.dtype))
+
+
+#: the static settings of every sparse loop program
+LOOP_ARGS = ("max_iterations", "damping", "kappa", "s_mode", "stop",
+             "patience")
+
+
+def sweep_loop(s3k, idx, until=None, carry=None, *, max_iterations: int,
+               damping: float, kappa: float, s_mode: str, stop: str,
+               patience: int, exchange: Optional[Exchange] = None,
+               count_mask=None, axis_name: Optional[str] = None):
+    """The sparse loop every program runs: ``init_state``, the sweep of
+    ``make_topk_sweep`` and ``dense.drive_sweeps``' stopping rule.
+
+    Without ``until`` it runs the whole loop and returns
+    ``(state, exemplars, n_sweeps, converged, trace)``; with one it runs
+    one checkpoint segment, from ``carry`` (None: a fresh start), and
+    returns the raw carry ``(state, e_prev, stable, it, trace)``. Inside
+    ``shard_map`` (``repro.solver.topk_sharded``) ``exchange`` is the
+    shard's, ``axis_name`` its mesh axis and ``count_mask`` its real
+    rows."""
+    s3k = jax.tree.map(lambda b: b.astype(jnp.float32), s3k)
+    init = init_state(s3k)
+    levels, n = init.c.shape
+    if axis_name is not None:
+        # tau/phi/c/col come out of init_state as fresh constants; the
+        # loop carries device-varying replacements, so mark them up front
+        def vary(x):
+            return jax.lax.pcast(x, (axis_name,), to="varying")
+        init = init._replace(tau=vary(init.tau), phi=vary(init.phi),
+                             c=vary(init.c), col=jax.tree.map(vary, init.col))
+    sweep, assign = make_topk_sweep(idx, damping=damping, kappa=kappa,
+                                    s_mode=s_mode, exchange=exchange)
+    return dense.drive_sweeps(
+        init, sweep, assign, levels, n, max_iterations=max_iterations,
+        stop=stop, patience=patience, count_mask=count_mask,
+        axis_name=axis_name, carry=carry, until=until)
 
 
 def run_topk(
@@ -313,18 +381,16 @@ def run_topk(
     Same return contract as ``run_dense``:
     ``(state, exemplars, n_sweeps, converged, trace)``.
     """
-    s3k = jax.tree.map(lambda b: b.astype(jnp.float32), s3k)
-    init = init_state(s3k)
-    levels, n = init.c.shape
-    sweep, assign = make_topk_sweep(idx, damping=damping, kappa=kappa,
-                                    s_mode=s_mode)
-
-    state, e, n_sweeps, conv, trace = dense.drive_sweeps(
-        init, sweep, assign, levels, n, max_iterations=max_iterations,
-        stop=stop, patience=patience)
+    state, e, n_sweeps, conv, trace = sweep_loop(
+        s3k, idx, max_iterations=max_iterations, damping=damping,
+        kappa=kappa, s_mode=s_mode, stop=stop, patience=patience)
     return TopKState(state, idx), e, n_sweeps, conv, trace
 
 
 run_topk.__name__ = SWEEP_PROGRAM         # the XLA program's name, see there
-run_topk = jax.jit(run_topk, static_argnames=(
-    "max_iterations", "damping", "kappa", "s_mode", "stop", "patience"))
+run_topk = jax.jit(run_topk, static_argnames=LOOP_ARGS)
+
+#: one checkpoint segment of the single-device loop
+#: (``repro.solver.checkpointing``): ``(s3k, idx, until, carry)``, the
+#: settings as keywords; a fresh and a resumed segment compile once each
+run_segment = jax.jit(sweep_loop, static_argnames=LOOP_ARGS)

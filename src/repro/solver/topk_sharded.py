@@ -1,24 +1,24 @@
 """Row-sharded ``dense_topk`` sweeps: the distributed message-passing loop.
 
-PR 5 sharded the top-k similarity *build*; this module shards the
-*sweeps* — the piece that makes per-device runtime AND per-device state
+The (L, N, k+1) message tensors are row-sharded over the 1-D
+``workers`` mesh, which makes per-device runtime AND per-device state
 linear in worker count (the paper's 80-VM experiment, realized on the
-compressed layout). The (L, N, k+1) message tensors are row-sharded over
-the 1-D ``workers`` mesh and the whole Jacobi loop — ``hap.jacobi_sweep``
-bodies through ``dense.drive_sweeps``'s stopping rule — runs inside ONE
-``shard_map``, so a converged run launches a single device program, not
-one dispatch per sweep.
+compressed layout). The sweep is ``topk.make_topk_sweep``'s and the loop
+``topk.sweep_loop``'s, run whole inside ONE ``shard_map``, so a
+converged run launches a single device program, not one dispatch per
+sweep. A row shard supplies only what differs from one device: its
+column exchange (``_exchange``), its mesh axis and its real rows.
 
 Per-sweep dataflow on each worker (B = N/W local rows):
 
-* rho (Eq 2.1), phi (2.5), c (2.6), the Eq 2.7 refinement, and the
-  Eq 2.8 decode are row reductions — shard-local, unchanged ops from
-  ``repro.kernels.topk_ops``.
-* the availability/tau column statistics (Eqs 2.2-2.4) sum max(0, rho)
+* rho (Eq 2.1), phi (2.5), c (2.6), tau (2.4), the Eq 2.7 refinement
+  and the Eq 2.8 decode are row-wise — shard-local, the one-device ops.
+* the availability column statistics (Eqs 2.2-2.3) sum max(0, rho)
   over *incoming* edges, whose sources live on other workers. That one
   primitive becomes an explicit exchange (``SolveConfig.exchange``), made
-  once a level a sweep in the alpha update; each worker carries its rows
-  of the exchanged sums to the next sweep's tau, which exchanges nothing:
+  once a level a sweep in the alpha update, which also all-gathers
+  c + phi; each worker carries its rows of the exchanged sums to the
+  next sweep's tau, which exchanges nothing:
 
   ``allgather`` — workers all-gather the (B, k+1) rho blocks and re-run
   the oracle's own scatter over the full edge set. Accumulation order is
@@ -61,14 +61,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import hap
-from repro.kernels.topk_ops import (
-    alpha_from_stats, assignments_topk, c_topk, col_partial_topk,
-    col_stats_topk, phi_topk, rho_topk, s_next_topk, tau_from_stats,
-)
-from repro.runtime.trace import SCOPE_GATHER
+from repro.kernels.topk_ops import col_partial_topk, col_stats_topk
 from repro.sharding.partitioning import device_put_row_sharded
-from repro.solver import dense
-from repro.solver.topk import TopKState, init_state
+from repro.solver.topk import Exchange, TopKState, sweep_loop
 
 AXIS = "workers"
 
@@ -160,129 +155,58 @@ def comm_bytes_per_sweep(n: int, k: int, levels: int, workers: int,
 
 
 # ----------------------------------------------------------------- program
+def _exchange(kind: str, idx_loc, n_total: int) -> Exchange:
+    """A row shard's column exchange (module docstring) for
+    ``make_topk_sweep``: one level's statistics, full-length vectors."""
+    def gather(v):
+        return jax.lax.all_gather(v, AXIS, axis=0, tiled=True)
+
+    if kind == "allgather":
+        idx_full = gather(idx_loc)
+
+        def stats(r_l):                            # the oracle's scatter
+            return col_stats_topk(gather(r_l), idx_full)
+    else:
+        def stats(r_l):
+            col = jax.lax.psum(col_partial_topk(r_l, idx_loc, n_total), AXIS)
+            return col, gather(r_l[:, 0])
+    return Exchange(n_total, stats, gather)
+
+
 @functools.lru_cache(maxsize=32)
-def _sharded_program(mesh, levels: int, n_local: int, n_total: int,
-                     n_real: int, kk: int, max_iterations: int,
-                     damping: float, kappa: float, s_mode: str, stop: str,
-                     patience: int, exchange: str,
-                     segmented: bool = False, with_carry: bool = False):
-    """Jitted whole-loop shard_map program, cached per mesh/config so
-    repeated solves hit XLA's compile cache (the ``_mrhap_program``
-    idiom).
+def _sharded_program(mesh, n_real: int, exchange: str, **loop):
+    """``topk.sweep_loop`` row-sharded in one jitted ``shard_map``,
+    cached per mesh/config so repeated solves hit XLA's compile cache
+    (the ``_mrhap_program`` idiom); ``loop`` holds ``topk.LOOP_ARGS``.
 
-    ``segmented`` compiles the checkpoint-segment variant
-    (``repro.solver.checkpointing``): an extra replicated (1,) ``until``
-    operand bounds the while_loop (dynamic, so every segment of a solve
-    reuses ONE compiled program), and the raw loop carry comes back
-    instead of the finished contract. ``with_carry`` additionally takes
-    the previous segment's carry — sharded state/exemplars plus the
-    replicated stable/it/trace — as inputs; two compilations total
-    (fresh first segment, resumed segments), regardless of how many
-    segment boundaries a solve crosses."""
+    Called as ``(s3k, idx)`` it runs the whole loop; given a scalar
+    ``until`` (and a ``carry``) one checkpoint segment, as
+    ``topk.run_segment`` does (``repro.solver.checkpointing``). jit
+    traces each form once, so a checkpointed solve compiles two programs
+    (fresh first segment, resumed segments) however many boundaries it
+    crosses. The carry's stable/it/trace stay device-invariant (the
+    change counter is psum-ed): they go in and out replicated."""
 
-    def body(s_loc: jnp.ndarray, idx_loc: jnp.ndarray, *rest):
-        rows = idx_loc[:, 0]                       # global row ids (self slot)
-        if exchange == "allgather":
-            idx_full = jax.lax.all_gather(idx_loc, AXIS, axis=0, tiled=True)
+    def program(s3k, idx, until=None, carry=None):
+        levels, n_total, _ = s3k.shape
 
-        def col_stats(r_l):
-            """Full-length (N_total,) availability column sum + rho self
-            slots — the one cross-worker reduction in the sweep."""
-            if exchange == "allgather":
-                r_full = jax.lax.all_gather(r_l, AXIS, axis=0, tiled=True)
-                return col_stats_topk(r_full, idx_full)   # oracle scatter
-            col = jax.lax.psum(
-                col_partial_topk(r_l, idx_loc, n_total), AXIS)
-            rdiag = jax.lax.all_gather(r_l[:, 0], AXIS, axis=0, tiled=True)
-            return col, rdiag
+        def body(s_loc, idx_loc, until, carry):
+            return sweep_loop(
+                s_loc, idx_loc, until, carry, **loop,
+                exchange=_exchange(exchange, idx_loc, n_total),
+                count_mask=idx_loc[:, 0] < n_real, axis_name=AXIS)
 
-        def tau_red(r_lv, c_lv, col=None):         # (L-1, B, kk), (L-1, B)x2
-            if levels == 1:
-                return jnp.zeros((0, n_local), s_loc.dtype)
-            return jnp.stack([tau_from_stats(c_lv[l], r_lv[l][:, 0], col[l])
-                              for l in range(levels - 1)])
+        row3, row2 = P(None, AXIS, None), P(None, AXIS)
+        state = hap.HAPState(s=row3, r=row3, a=row3, tau=row2, phi=row2,
+                             c=row2, col=None if levels == 1 else row2)
+        out = (state, row2, P(), P(), P())
+        return jax.shard_map(
+            body, mesh=mesh, out_specs=out,
+            in_specs=(row3, P(AXIS, None), P(),
+                      P() if carry is None else out),
+        )(s3k, idx, until, carry)
 
-        reducers = hap.SweepReducers(
-            tau=tau_red,
-            phi=jax.vmap(phi_topk),
-            c=jax.vmap(c_topk),
-            s_next=lambda s_up, a, r, kap, mode: jax.vmap(
-                lambda su, al, rl: s_next_topk(su, al, rl, kap, mode)
-            )(s_up, a, r))
-
-        def update_r(s, a, tau, r):
-            return hap._damp(r, jax.vmap(rho_topk)(s, a, tau), damping)
-
-        def update_a(r, c, phi, a):
-            new, cols = [], []
-            for l in range(levels):                # L static: unrolled
-                col, rdiag = col_stats(r[l])
-                base = jax.lax.all_gather(c[l] + phi[l], AXIS, axis=0,
-                                          tiled=True)
-                new.append(alpha_from_stats(r[l], idx_loc, col, base, rdiag))
-                cols.append(col)
-            a = hap._damp(a, jnp.stack(new), damping)
-            if levels == 1:
-                return a
-            with jax.named_scope(SCOPE_GATHER):    # the rows tau reads
-                return a, jnp.stack([col[rows] for col in cols[:-1]])
-
-        def sweep(state, it):
-            return hap.jacobi_sweep(
-                state, it == 0, lam=damping, kappa=kappa, s_mode=s_mode,
-                update_r=update_r, update_a=update_a, reducers=reducers)
-
-        def assign(state):
-            return jax.vmap(
-                lambda al, rl: assignments_topk(al, rl, idx_loc,
-                                                n_total=n_total)
-            )(state.a, state.r)
-
-        init = init_state(s_loc)
-        # tau/phi/c/col come out of init_state as fresh constants; the
-        # loop carries device-varying replacements, so mark them up front.
-        vary = lambda x: jax.lax.pcast(x, (AXIS,), to="varying")
-        init = init._replace(tau=vary(init.tau), phi=vary(init.phi),
-                             c=vary(init.c), col=jax.tree.map(vary, init.col))
-        scal = lambda v: vary(jnp.reshape(v, (1,)))
-
-        if not segmented:
-            state, e, n_sweeps, conv, trace = dense.drive_sweeps(
-                init, sweep, assign, levels, n_local,
-                max_iterations=max_iterations, stop=stop, patience=patience,
-                count_mask=rows < n_real, axis_name=AXIS)
-            return state, e, scal(n_sweeps), scal(conv), vary(trace)[None]
-
-        # segment variant: rest = (until[, carry...]). stable/it/trace
-        # stay device-invariant through the loop (the change counter is
-        # psum-ed), so the replicated carry inputs match without a pcast.
-        until = rest[0][0]
-        if with_carry:
-            c_state, c_e, c_stable, c_it, c_trace = rest[1:]
-            carry = (c_state, c_e, c_stable[0], c_it[0], c_trace)
-        else:
-            carry = None
-        state, e, stable, it, trace = dense.drive_sweeps(
-            init, sweep, assign, levels, n_local,
-            max_iterations=max_iterations, stop=stop, patience=patience,
-            count_mask=rows < n_real, axis_name=AXIS,
-            segmented=True, carry=carry, until=until)
-        return state, e, scal(stable), scal(it), vary(trace)[None]
-
-    row3 = P(None, AXIS, None)
-    row2 = P(None, AXIS)
-    state_spec = hap.HAPState(s=row3, r=row3, a=row3,
-                              tau=row2, phi=row2, c=row2,
-                              col=None if levels == 1 else row2)
-    in_specs = [row3, P(AXIS, None)]
-    if segmented:
-        in_specs.append(P(None))                   # until
-        if with_carry:
-            in_specs += [state_spec, row2, P(None), P(None), P(None)]
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh,
-        in_specs=tuple(in_specs),
-        out_specs=(state_spec, row2, P(AXIS), P(AXIS), P(AXIS, None))))
+    return jax.jit(program)
 
 
 def run_topk_sharded(
@@ -297,9 +221,9 @@ def run_topk_sharded(
     stop: str = "fixed",
     patience: int = 5,
     exchange: str = "auto",
-    axis_name: str = AXIS,
 ):
-    """Run the sparse Jacobi schedule row-sharded over ``mesh[axis_name]``.
+    """Run the sparse Jacobi schedule row-sharded over ``mesh``'s
+    ``workers`` axis.
 
     Same return contract as ``run_topk`` —
     ``(TopKState, exemplars, n_sweeps, converged, trace)`` — with
@@ -308,23 +232,21 @@ def run_topk_sharded(
     exchange, which keeps its scatter order) and ``stop="converged"``
     exits on the same sweep with the same trace.
     """
-    if tuple(mesh.axis_names) != (axis_name,):
+    if tuple(mesh.axis_names) != (AXIS,):
         raise ValueError(
-            f"sharded sweeps need a 1-D mesh with axis {axis_name!r} "
+            f"sharded sweeps need a 1-D mesh with axis {AXIS!r} "
             f"(got axes {tuple(mesh.axis_names)}); build one with "
             "repro.launch.mesh.make_worker_mesh()")
     s3k = s3k.astype(jnp.float32)
-    levels, n, kk = s3k.shape
-    w = mesh.shape[axis_name]
-    s3k_p, idx_p, n_real = pad_topk(s3k, idx, w)
-    n_total = s3k_p.shape[1]
-    exchange = resolve_exchange(exchange, n=n_total, kk=kk)
+    kk = s3k.shape[2]
+    s3k_p, idx_p, n_real = pad_topk(s3k, idx, mesh.shape[AXIS])
     fn = _sharded_program(
-        mesh, levels, n_total // w, n_total, n_real, kk, max_iterations,
-        damping, kappa, s_mode, stop, patience, exchange)
+        mesh, n_real, resolve_exchange(exchange, n=s3k_p.shape[1], kk=kk),
+        max_iterations=max_iterations, damping=damping, kappa=kappa,
+        s_mode=s_mode, stop=stop, patience=patience)
     # place row blocks on their owners up front: jit would otherwise
     # first replicate the full stack onto every device
-    s3k_p = device_put_row_sharded(s3k_p, mesh, axis_name, axis=1)
-    idx_p = device_put_row_sharded(idx_p, mesh, axis_name, axis=0)
+    s3k_p = device_put_row_sharded(s3k_p, mesh, AXIS, axis=1)
+    idx_p = device_put_row_sharded(idx_p, mesh, AXIS, axis=0)
     state, e, n_sweeps, conv, trace = fn(s3k_p, idx_p)
-    return TopKState(state, idx_p), e, n_sweeps[0], conv[0], trace[0]
+    return TopKState(state, idx_p), e, n_sweeps, conv, trace

@@ -36,11 +36,10 @@ def main() -> int:
         np.float32)
     s3k, idx = build_from_points(jnp.asarray(x), 8, 2)
     s3k_p, idx_p, n_real = pad_topk(s3k, idx, workers)
-    levels, n_total, kk = s3k_p.shape
     for exchange in ("psum", "allgather"):
-        fn = _sharded_program(mesh, levels, n_total // workers, n_total,
-                              n_real, kk, 2, 0.7, 0.0, "off", "fixed", 5,
-                              exchange)
+        fn = _sharded_program(mesh, n_real, exchange, max_iterations=2,
+                              damping=0.7, kappa=0.0, s_mode="off",
+                              stop="fixed", patience=5)
         text = fn.lower(s3k_p, idx_p).compile().as_text()
         ops = [list(m.groups()) for m in map(OP.match, text.splitlines())
                if m]

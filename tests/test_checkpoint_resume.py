@@ -123,6 +123,44 @@ def test_sharded_crash_resume_bit_exact(tmp_path):
     jax.tree.map(assert_values_close, state, o_state)
 
 
+@pytest.mark.parametrize("levels", [1, 3])
+@pytest.mark.parametrize("placement", ["single", "sharded"])
+def test_resumed_solve_equals_unsegmented(tmp_path, placement, levels):
+    """Both placements run one segment runner: a solve crashed at its
+    second boundary and resumed equals the same placement's unsegmented
+    loop bit for bit — exemplars, sweep count, trace, every state field
+    (the carried column sums included) and the column map."""
+    from repro.solver.topk_sharded import run_topk_sharded
+
+    x = _pts(n=96)
+    cfg = SolveConfig(k=12, levels=levels, stop="converged",
+                      max_iterations=25, patience=5, damping=0.7,
+                      preference="median", checkpoint_every=4,
+                      checkpoint_dir=str(tmp_path / "ck"))
+    s3k, idx = topk.build_from_points(
+        jnp.asarray(x), cfg.k, cfg.levels, metric=cfg.metric,
+        preference=cfg.preference, key=jax.random.PRNGKey(cfg.seed),
+        config=cfg)
+    loop = dict(max_iterations=cfg.max_iterations, damping=cfg.damping,
+                stop=cfg.stop, patience=cfg.patience)
+    if placement == "single":
+        mesh, plain = None, topk.run_topk(s3k, idx, **loop)
+    else:
+        mesh = make_worker_mesh()
+        plain = run_topk_sharded(s3k, idx, mesh, exchange=cfg.exchange,
+                                 **loop)
+    inj = FaultInjector().add(
+        Rule("solver.sweep", nth=1, match={"kind": placement}))
+    with faultinject.active(inj), pytest.raises(InjectedFault):
+        checkpointing.run_topk_checkpointed(s3k, idx, cfg, mesh=mesh)
+    resumed = checkpointing.run_topk_checkpointed(
+        s3k, idx, cfg.replace(resume_from=cfg.checkpoint_dir), mesh=mesh)
+    assert (resumed[0].hap.col is None) == (levels == 1)
+    assert jax.tree.structure(resumed) == jax.tree.structure(plain)
+    for got, want in zip(jax.tree.leaves(resumed), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # -------------------------------------------------------------- coarsen
 COARSEN_CFG = dict(backend="coarsen", partition_size=64, coarsen_batch=2,
                    stop="converged", max_iterations=60, patience=5,

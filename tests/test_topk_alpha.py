@@ -11,6 +11,8 @@ level, so the three gathers cannot come back unnoticed.
 Likewise one sweep holds one column-sum scatter-add a level per row
 block: alpha's, whose sums tau reads at the next sweep
 (``hap.jacobi_sweep``); tau scattering the same rho again made 2L - 1.
+The row-sharded program composes the same sweep, so it holds the same
+count under either exchange.
 """
 import re
 
@@ -20,7 +22,9 @@ import numpy as np
 import pytest
 
 from repro.kernels.topk_ops import alpha_from_stats
+from repro.launch.mesh import make_worker_mesh
 from repro.solver.topk import init_state, make_topk_sweep
+from repro.solver.topk_sharded import _sharded_program
 
 N, KK = 64, 9
 
@@ -91,13 +95,16 @@ SCATTER = re.compile(r'"stablehlo\.scatter".*\n.*\^bb0.*\n\s*(?:%\S+ = )?'
 
 
 @pytest.mark.parametrize("levels", [1, 2, 3])
-@pytest.mark.parametrize("blocks", [1, 2], ids=["one_layout", "row_blocks"])
-def test_sweep_scatters_once_per_level(levels, blocks):
+@pytest.mark.parametrize("layout", ["one_layout", "row_blocks", "allgather",
+                                    "psum"])
+def test_sweep_scatters_once_per_level(levels, layout):
     """L column-sum scatter-adds a sweep on one layout, L per block on
-    row blocks (the second block keeps 5 of its rows' 9 slots)."""
+    row blocks (the second block keeps 5 of its rows' 9 slots), and L in
+    the row-sharded program on one worker under either exchange (its
+    fixed-budget loop holds one sweep)."""
     rng = np.random.default_rng(3)
     idx, vals = _idx(rng), _values(rng, (N, KK), "normal")
-    if blocks == 2:
+    if layout == "row_blocks":
         h = N // 2
         idx, vals = (idx[:h], idx[h:, :5]), (vals[:h], vals[h:, :5])
     else:
@@ -105,8 +112,16 @@ def test_sweep_scatters_once_per_level(levels, blocks):
     s3k = tuple(jnp.asarray(np.broadcast_to(v, (levels, *v.shape)))
                 for v in vals)
     idx = tuple(map(jnp.asarray, idx))
-    if blocks == 1:
+    if layout != "row_blocks":
         s3k, idx = s3k[0], idx[0]
-    found = SCATTER.findall(_sweep_text(s3k, idx))
+    if layout in ("allgather", "psum"):
+        program = _sharded_program(
+            make_worker_mesh(), N, layout, max_iterations=2, damping=0.7,
+            kappa=0.0, s_mode="off", stop="fixed", patience=5)
+        text = program.lower(s3k, idx).as_text()
+    else:
+        text = _sweep_text(s3k, idx)
+    found = SCATTER.findall(text)
     adds = [shape for op, shape in found if op == "add"]
+    blocks = 2 if layout == "row_blocks" else 1
     assert adds == [f"{N}xf32"] * (levels * blocks)

@@ -45,13 +45,15 @@ def _dup_points(n=150, seed=3):
 
 
 # ---------------------------------------------------------------- parity
+@pytest.mark.parametrize("levels", [1, 2, 3])
 @pytest.mark.parametrize("stop", ["fixed", "converged"])
 @pytest.mark.parametrize("exchange", ["allgather", "psum"])
-def test_single_worker_mesh_bit_exact(stop, exchange):
+def test_single_worker_mesh_bit_exact(stop, exchange, levels):
     """W=1 runs the full shard_map program (identity collectives); both
     exchanges must reproduce the oracle's exemplars, trace and stop
-    exactly, and its message state within the numeric contract."""
-    s3k, idx = build_from_points(jnp.asarray(_dup_points()), 12, 3)
+    exactly, and its message state within the numeric contract — the
+    carried column sums too, at every level count."""
+    s3k, idx = build_from_points(jnp.asarray(_dup_points()), 12, levels)
     st, e, ns, conv, tr = run_topk(
         s3k, idx, max_iterations=25, damping=0.7, stop=stop, patience=5)
     st2, e2, ns2, conv2, tr2 = run_topk_sharded(
@@ -61,7 +63,9 @@ def test_single_worker_mesh_bit_exact(stop, exchange):
     np.testing.assert_array_equal(np.asarray(e), np.asarray(e2)[:, :n])
     np.testing.assert_array_equal(np.asarray(tr), np.asarray(tr2))
     assert int(ns) == int(ns2) and bool(conv) == bool(conv2)
-    for f in ("s", "r", "a", "tau", "phi", "c"):
+    assert (st.hap.col is None) == (levels == 1)
+    assert (st2.hap.col is None) == (levels == 1)
+    for f in ("s", "r", "a", "tau", "phi", "c", "col")[:6 + (levels > 1)]:
         assert_values_close(np.asarray(getattr(st2.hap, f))[:, :n],
                             getattr(st.hap, f), err_msg=f)
 
